@@ -18,14 +18,12 @@ Rows (EXPERIMENTS.md "Bulk joins"):
 """
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
 import time
 
 import numpy as np
 
-from benchmarks.common import emit, emit_row, timeit
+from benchmarks.common import (cpu_mesh_subprocess, emit, emit_row,
+                               timeit)
 from repro.core import build
 from repro.graph import generators
 from repro.join import JoinConfig, compile_count, run_join
@@ -125,18 +123,10 @@ def run_mesh(n: int = 1000, mesh: int = 2, k: int = 16, tile: int = 64,
 
 def mesh_subprocess(mesh: int = 2, n: int = 500) -> None:
     """run.py --smoke hook: sharded sweep equivalence + recompile gate
-    in a subprocess with forced host devices."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={mesh}"
-    env.setdefault("PYTHONPATH", "src")
-    r = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_join",
-         "--mesh", str(mesh), "--n", str(n)],
-        capture_output=True, text=True, timeout=900, env=env)
-    assert "JOIN_MESH_OK" in r.stdout, r.stdout + r.stderr
-    for line in r.stdout.splitlines():
-        if line.startswith("join/"):
-            print(line)
+    in a CPU child with forced host devices."""
+    cpu_mesh_subprocess("benchmarks.bench_join",
+                        ["--mesh", str(mesh), "--n", str(n)],
+                        "JOIN_MESH_OK", "join/", mesh)
 
 
 def _main() -> None:

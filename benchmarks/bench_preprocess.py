@@ -10,12 +10,9 @@ drives through :func:`mesh_subprocess`.
 """
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
 import time
 
-from benchmarks.common import emit
+from benchmarks.common import cpu_mesh_subprocess, emit
 from repro.baselines import linearize, montecarlo
 from repro.core import diagonal, hp_index, theory, walks
 from repro.graph import generators
@@ -199,19 +196,11 @@ def run_mesh(n: int = 1000, mesh: int = 2, eps: float = 0.2,
 
 def mesh_subprocess(mesh: int = 2, n: int = 240) -> None:
     """run.py --smoke hook: 2-shard build equivalence + the diagonal
-    recompile gate in a subprocess (host devices must be forced before
+    recompile gate in a CPU child (host devices must be forced before
     the child's jax initializes)."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={mesh}"
-    env.setdefault("PYTHONPATH", "src")
-    r = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_preprocess",
-         "--mesh", str(mesh), "--n", str(n)],
-        capture_output=True, text=True, timeout=900, env=env)
-    assert "MESH_PREPROCESS_OK" in r.stdout, r.stdout + r.stderr
-    for line in r.stdout.splitlines():
-        if line.startswith("fig3/"):
-            print(line)
+    cpu_mesh_subprocess("benchmarks.bench_preprocess",
+                        ["--mesh", str(mesh), "--n", str(n)],
+                        "MESH_PREPROCESS_OK", "fig3/", mesh)
 
 
 def _main() -> None:

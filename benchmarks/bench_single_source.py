@@ -15,12 +15,11 @@ unset); ``run.py --smoke`` drives the 2-shard check through
 from __future__ import annotations
 
 import os
-import subprocess
-import sys
 
 import numpy as np
 
-from benchmarks.common import emit, emit_row, timeit
+from benchmarks.common import (cpu_mesh_subprocess, emit, emit_row,
+                               timeit)
 from repro.baselines import linearize
 from repro.core import build
 from repro.core.single_source import (single_source_device,
@@ -191,20 +190,13 @@ def run_mesh(n: int = 1000, mesh: int = 4, eps: float = 0.15,
 
 
 def mesh_subprocess(mesh: int = 2, n: int = 300) -> None:
-    """run.py --smoke hook: the sharded query check in a subprocess
+    """run.py --smoke hook: the sharded query check in a CPU child
     (host devices must be forced before the child's jax initializes).
     """
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={mesh}"
-    env.setdefault("PYTHONPATH", "src")
-    r = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_single_source",
-         "--mesh", str(mesh), "--n", str(n), "--queries", "16"],
-        capture_output=True, text=True, timeout=900, env=env)
-    assert "MESH_OK" in r.stdout, r.stdout + r.stderr
-    for line in r.stdout.splitlines():
-        if line.startswith("fig2/"):
-            print(line)
+    cpu_mesh_subprocess(
+        "benchmarks.bench_single_source",
+        ["--mesh", str(mesh), "--n", str(n), "--queries", "16"],
+        "MESH_OK", "fig2/", mesh)
 
 
 def _main() -> None:

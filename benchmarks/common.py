@@ -6,6 +6,8 @@ from __future__ import annotations
 import json
 import os
 import re
+import subprocess
+import sys
 import time
 
 import numpy as np
@@ -118,6 +120,30 @@ def write_json(mode: str, path: str | None = None,
         json.dump(doc, f, indent=1)
     print(f"# wrote {len(rows)} structured rows -> {path}")
     return path
+
+
+def cpu_mesh_subprocess(module: str, args: list[str], ok_marker: str,
+                        row_prefix: str, mesh: int) -> None:
+    """Run a forced-host-device sharding check in a child process.
+
+    The child is pinned to the CPU (``JAX_PLATFORMS=cpu``) with
+    ``mesh`` forced host devices: the parent may hold the accelerator,
+    and a chip belongs to one process at a time. Its ``row_prefix``
+    rows are re-emitted here under ``/platform=cpu``, so no reader
+    takes them for device numbers.
+    """
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={mesh}"
+    env.setdefault("PYTHONPATH", "src")
+    r = subprocess.run([sys.executable, "-m", module, *args],
+                       capture_output=True, text=True, timeout=900,
+                       env=env)
+    assert ok_marker in r.stdout, r.stdout + r.stderr
+    for line in r.stdout.splitlines():
+        if line.startswith(row_prefix):
+            name, us, derived = line.split(",", 2)
+            emit(f"{name}/platform=cpu", float(us), derived)
 
 
 # ----------------------------------------------------------------------

@@ -53,6 +53,8 @@ def main() -> None:
                          "regressions")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     def want(name: str) -> bool:
         return only is None or name in only

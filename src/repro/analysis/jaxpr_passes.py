@@ -31,7 +31,7 @@ _CALLBACK_MARKERS = ("callback", "outside_call", "infeed", "outfeed")
 # here): recursive eqn iteration through jit/scan/while sub-jaxprs
 # ----------------------------------------------------------------------
 def sub_jaxprs(v):
-    from jax import core
+    from jax.extend import core
     if isinstance(v, core.Jaxpr):
         return [v]
     if isinstance(v, core.ClosedJaxpr):

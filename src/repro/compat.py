@@ -1,11 +1,9 @@
-"""Version-tolerant wrappers for jax APIs that moved across releases.
+"""The repo's one spelling of jax APIs that moved across releases.
 
-The code targets the modern ``jax.shard_map`` / ``jax.make_mesh(...,
-axis_types=...)`` spelling; older pins (<= 0.4.x) still have shard_map
-in ``jax.experimental.shard_map`` (with ``check_rep``/``auto`` instead
-of ``check_vma``/``axis_names``) and meshes without axis types. Every
-call site goes through these shims so a version bump is a one-file
-change.
+Written for the installed jax (0.9): ``jax.shard_map`` with
+``axis_names``/``check_vma`` and ``jax.make_mesh(..., axis_types=...)``.
+Every call site goes through these helpers, so a version bump is a
+one-file change.
 """
 from __future__ import annotations
 
@@ -28,29 +26,19 @@ def segment_sum(data, segment_ids, num_segments: int):
 
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types where supported."""
-    try:
-        return jax.make_mesh(
-            tuple(axis_shapes), tuple(axis_names),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-    except (AttributeError, TypeError):
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
+    """``jax.make_mesh`` with every axis of type Auto."""
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None):
     """``jax.shard_map`` without varying-manual-axes checks.
 
     ``axis_names``: the manually-mapped mesh axes (defaults to all).
-    On old jax this maps to ``auto = mesh axes - axis_names`` and
-    ``check_rep=False``; on new jax to ``axis_names``/``check_vma``.
     """
     names = (frozenset(axis_names) if axis_names is not None
              else frozenset(mesh.axis_names))
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=names,
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    auto = frozenset(mesh.axis_names) - names
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False, auto=auto)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=names,
+                         check_vma=False)

@@ -55,6 +55,7 @@ from repro import compat
 from repro.core import hp_index
 from repro.core.single_source import horner_push, prune_tau
 from repro.graph import csr
+from repro.kernels import interpret_mode
 from repro.launch.sharding import sling_index_specs
 
 
@@ -471,7 +472,7 @@ def sharded_single_source(si: ShardedIndex, us,
             si.pblk_w, us, jnp.float32(si.tau), mesh=si.mesh,
             axis=si.axis, n=si.n, n_loc=si.n_loc, l_max=si.l_max,
             bn=si.bn, eb=si.eb,
-            interpret=jax.default_backend() != "tpu")
+            interpret=interpret_mode())
     else:
         out = _sharded_source(
             si.keys, si.vals, si.d, si.blk_src, si.blk_dstl, si.blk_w,
@@ -497,7 +498,7 @@ def sharded_topk(si: ShardedIndex, us, k: int,
             si.pblk_w, us, jnp.float32(si.tau), mesh=si.mesh,
             axis=si.axis, n=si.n, n_loc=si.n_loc, l_max=si.l_max,
             k=k, bn=si.bn, eb=si.eb,
-            interpret=jax.default_backend() != "tpu")
+            interpret=interpret_mode())
     else:
         v, i = _sharded_topk(
             si.keys, si.vals, si.d, si.blk_src, si.blk_dstl, si.blk_w,

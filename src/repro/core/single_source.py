@@ -176,8 +176,7 @@ def batched_single_source(keys, vals, d, edge_src, edge_dst, w,
 @partial(jax.jit, static_argnames=("n", "l_max", "bn", "eb", "interpret"))
 def batched_single_source_pallas(keys, vals, d, blk_src, blk_dstl,
                                  blk_w, us, tau, n: int, l_max: int,
-                                 bn: int, eb: int,
-                                 interpret: bool = True):
+                                 bn: int, eb: int, interpret: bool):
     """Pallas-backed twin of :func:`batched_single_source`.
 
     Same (B, n) float32 result (up to float32 reduction order -- the
@@ -203,6 +202,7 @@ def single_source_device(idx, g: csr.Graph, us: np.ndarray,
     process-wide switch, ``repro.kernels.horner_push``).
     """
     from repro.core import device_state
+    from repro.kernels import interpret_mode
     from repro.kernels.horner_push import resolve_push_backend
     st = device_state.serving_arrays(idx, g)
     if resolve_push_backend(backend) == "pallas":
@@ -211,7 +211,7 @@ def single_source_device(idx, g: csr.Graph, us: np.ndarray,
             st.keys, st.vals, st.d, bl.blk_src, bl.blk_dstl, bl.blk_w,
             jnp.asarray(us, jnp.int32), jnp.float32(st.tau),
             idx.n, idx.plan.l_max, bl.bn, bl.eb,
-            interpret=jax.default_backend() != "tpu")
+            interpret=interpret_mode())
     else:
         out = batched_single_source(
             st.keys, st.vals, st.d, st.edge_src, st.edge_dst, st.w,

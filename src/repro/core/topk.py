@@ -51,7 +51,7 @@ def batched_topk(keys, vals, d, edge_src, edge_dst, w, us, tau,
          static_argnames=("n", "l_max", "k", "bn", "eb", "interpret"))
 def batched_topk_pallas(keys, vals, d, blk_src, blk_dstl, blk_w, us,
                         tau, n: int, l_max: int, k: int, bn: int,
-                        eb: int, interpret: bool = True):
+                        eb: int, interpret: bool):
     """Pallas-backed twin of :func:`batched_topk`: the fused Horner
     push kernel feeds the same ``jax.lax.top_k`` selection inside one
     XLA program, so the backend switch changes only the push body --
@@ -79,6 +79,7 @@ def topk_device(idx, g: csr.Graph, us: np.ndarray, k: int,
     process-wide switch, ``repro.kernels.horner_push``).
     """
     from repro.core import device_state
+    from repro.kernels import interpret_mode
     from repro.kernels.horner_push import resolve_push_backend
     k = min(int(k), idx.n)
     st = device_state.serving_arrays(idx, g)
@@ -88,7 +89,7 @@ def topk_device(idx, g: csr.Graph, us: np.ndarray, k: int,
             st.keys, st.vals, st.d, bl.blk_src, bl.blk_dstl, bl.blk_w,
             jnp.asarray(us, jnp.int32), jnp.float32(st.tau),
             idx.n, idx.plan.l_max, k, bl.bn, bl.eb,
-            interpret=jax.default_backend() != "tpu")
+            interpret=interpret_mode())
     else:
         top_v, top_i = batched_topk(
             st.keys, st.vals, st.d, st.edge_src, st.edge_dst, st.w,

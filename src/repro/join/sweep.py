@@ -183,10 +183,10 @@ def _tile_runner(idx, g, cfg: JoinConfig, kq: int):
     reuses the one compiled program."""
     backend = _resolved_backend(cfg)
     if cfg.mesh is None:
-        import jax
         import jax.numpy as jnp
         from repro.core import device_state
         from repro.core.topk import batched_topk, batched_topk_pallas
+        from repro.kernels import interpret_mode
         st = device_state.serving_arrays(idx, g)
         if backend == "pallas":
             bl = device_state.blocked_push_arrays(idx, g)
@@ -197,7 +197,7 @@ def _tile_runner(idx, g, cfg: JoinConfig, kq: int):
                     bl.blk_w, jnp.asarray(us, jnp.int32),
                     jnp.float32(st.tau), idx.n, idx.plan.l_max, kq,
                     bl.bn, bl.eb,
-                    interpret=jax.default_backend() != "tpu")
+                    interpret=interpret_mode())
                 return np.asarray(v), np.asarray(i)
             return run_tile
 

@@ -34,7 +34,7 @@ def _kernel(x0_ref, xk_ref, w_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("bb", "interpret"))
-def cin_layer(x0, xk, W, *, bb: int = 64, interpret: bool = True):
+def cin_layer(x0, xk, W, *, interpret: bool, bb: int = 64):
     """x0 (B, m, D), xk (B, h, D), W (h', h, m) -> (B, h', D)."""
     B, m, D = x0.shape
     h = xk.shape[1]

@@ -9,9 +9,10 @@ tau-prune, and Horner seed-accumulate into one grid program
 serving/join layers consult:
 
   * ``set_push_backend("lax" | "pallas" | "auto")`` / environment
-    variable ``SLING_PUSH_BACKEND`` -- "auto" resolves to "pallas" on a
-    TPU backend and "lax" elsewhere, so CPU CI keeps the reference path
-    unless a test opts in;
+    variable ``SLING_PUSH_BACKEND`` -- "auto" resolves to "lax" on
+    every platform: the fused kernel's in-kernel frontier row gather is
+    refused by the v5e compiler, so it is selected only explicitly (and
+    on a TPU then fails at compile time);
   * ``resolve_push_backend(name)`` -- resolve a config value ("auto"
     defers to the process switch);
   * ``use_push_backend(name)`` -- context manager for tests.
@@ -47,19 +48,15 @@ def resolve_push_backend(name: str | None = None) -> str:
     """Resolve a config value to a concrete backend.
 
     ``None``/"auto" defer to the process switch; a process switch of
-    "auto" resolves by device: pallas on TPU, lax elsewhere (the kernel
-    runs everywhere via interpret mode, but on CPU the lax path is the
-    faster *production* choice -- interpret mode exists for CI).
+    "auto" resolves to lax on every platform (the fused kernel does not
+    compile for a TPU yet; off the chip it runs only interpreted).
     """
     name = name or "auto"
     if name not in _VALID:
         raise ValueError(f"push backend {name!r} not in {_VALID}")
     if name == "auto":
         name = _backend
-    if name == "auto":
-        import jax
-        name = "pallas" if jax.default_backend() == "tpu" else "lax"
-    return name
+    return "lax" if name == "auto" else name
 
 
 @contextlib.contextmanager

@@ -67,8 +67,7 @@ def _step_kernel(ls_ref, kloc_ref, contrib_ref, src_ref, dstl_ref,
 
 
 def horner_step(x, ls, kloc, contrib, blk_src, blk_dstl, blk_w, tau,
-                lvl, *, bn: int, eb: int, bq: int,
-                interpret: bool = True):
+                lvl, *, bn: int, eb: int, bq: int, interpret: bool):
     """One fused push step: returns seed_l + A_hat @ prune(x).
 
     x (n_frontier, B) f32 node-major frontier; ls/kloc/contrib (B, W)

@@ -81,7 +81,7 @@ def horner_push_pallas(ku, xu, d, blk_src, blk_dstl, blk_w, tau, *,
                        eb: int = DEFAULT_EB, bq: int = 8,
                        slab_start: int = 0, slab_size: int | None = None,
                        d_offset: int | None = None, gather=None,
-                       interpret: bool = True):
+                       interpret: bool):
     """Drop-in Pallas backend for ``single_source.horner_push``.
 
     Same argument contract and (B, slab_size) float32 return, except

@@ -1,12 +1,15 @@
-"""jit'd wrapper: batched single-pair queries through the join kernel."""
+"""jit'd wrappers: batched single-pair queries through the join kernel."""
 from __future__ import annotations
 
+from functools import partial
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-import jax.numpy as jnp
-
+from repro.kernels import interpret_mode
 from repro.kernels.hp_join import ref as ref_mod
-from repro.kernels.hp_join.hp_join import hp_join
+from repro.kernels.hp_join.hp_join import PAD, hp_join
 
 
 def fold_sqrt_d(index):
@@ -19,23 +22,34 @@ def fold_sqrt_d(index):
     ks = (keys.astype(np.int64) % n).clip(0, n - 1)
     sd = np.sqrt(np.maximum(index.d.astype(np.float64), 0.0))
     folded = (vals * sd[ks]).astype(np.float32)
-    folded[keys == np.int32(2**31 - 1)] = 0.0
+    folded[keys == np.int32(PAD)] = 0.0
     return keys, folded
 
 
-def query_pairs_kernel(index, us, vs, bq: int = 8,
-                       interpret: bool = True) -> np.ndarray:
+@partial(jax.jit, static_argnames=("n", "interpret"))
+def pair_query_batch_pallas(keys, vals, d, us, vs, *, n: int,
+                            interpret: bool):
+    """Pallas twin of ``core.index._pair_query_batch``: same device
+    arrays and (B,) float32 result. The sqrt(d_k) fold of ref.py is
+    applied to the gathered (B, K) rows here, so the serving state
+    needs no second, folded copy of the packed table."""
+    sd = jnp.sqrt(jnp.maximum(d, 0.0))
+
+    def fold(k, x):
+        return jnp.where(k == PAD, 0.0,
+                         x * sd[jnp.clip(k % n, 0, n - 1)])
+
+    ku, kv = keys[us], keys[vs]
+    return hp_join(ku, fold(ku, vals[us]), kv, fold(kv, vals[vs]),
+                   interpret=interpret)
+
+
+def query_pairs_kernel(index, us, vs, bq: int = 128) -> np.ndarray:
     keys, folded = fold_sqrt_d(index)
-    B = len(us)
-    pad = (-B) % bq
-    us_p = np.concatenate([us, np.zeros(pad, us.dtype)])
-    vs_p = np.concatenate([vs, np.zeros(pad, vs.dtype)])
-    ku = jnp.asarray(keys[us_p])
-    vu = jnp.asarray(folded[us_p])
-    kv = jnp.asarray(keys[vs_p])
-    vv = jnp.asarray(folded[vs_p])
-    out = hp_join(ku, vu, kv, vv, bq=bq, interpret=interpret)
-    return np.asarray(out)[:B]
+    out = hp_join(jnp.asarray(keys[us]), jnp.asarray(folded[us]),
+                  jnp.asarray(keys[vs]), jnp.asarray(folded[vs]),
+                  bq=bq, interpret=interpret_mode())
+    return np.asarray(out)
 
 
 def query_pairs_reference(index, us, vs) -> np.ndarray:

@@ -6,6 +6,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro.graph import csr
+from repro.kernels import interpret_mode
 from repro.kernels.spmv_ell import ref as ref_mod
 from repro.kernels.spmv_ell.spmv_ell import spmm_block
 
@@ -32,13 +33,12 @@ def block_align(g: csr.Graph, w: np.ndarray, bn: int, eb: int):
     return blk_src, blk_dstl, blk_w
 
 
-def spmm(x, g: csr.Graph, w: np.ndarray, bn: int = 8, eb: int = 16,
-         interpret: bool = True):
+def spmm(x, g: csr.Graph, w: np.ndarray, bn: int = 8, eb: int = 16):
     """out[v] = sum_{u in I(v)} w_(u->v) * x[u]; kernel-backed."""
     blk_src, blk_dstl, blk_w = block_align(g, w, bn, eb)
     out = spmm_block(jnp.asarray(x, jnp.float32), jnp.asarray(blk_src),
                      jnp.asarray(blk_dstl), jnp.asarray(blk_w),
-                     bn=bn, eb=eb, interpret=interpret)
+                     bn=bn, eb=eb, interpret=interpret_mode())
     return out[: g.n]
 
 

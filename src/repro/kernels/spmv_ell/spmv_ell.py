@@ -45,7 +45,7 @@ def _kernel(src_ref, dstl_ref, w_ref, x_ref, o_ref, *, bn: int, eb: int):
 
 @functools.partial(jax.jit, static_argnames=("bn", "eb", "interpret"))
 def spmm_block(x, blk_src, blk_dst_local, blk_w, *, bn: int, eb: int,
-               interpret: bool = True):
+               interpret: bool):
     """x (N, F) f32; blk_* (NB, E_pad) block-aligned edges.
 
     Returns (NB*bn, F). E_pad must be a multiple of eb.

@@ -9,8 +9,11 @@ only parses flags, generates traffic, and reports latency.
 
 ``--mesh S`` serves node-sharded: the index partitions over an S-way
 "data" mesh axis and single-source/top-k fan out with shard_map
-(DESIGN.md section 8). On CPU the S host devices are forced via
-XLA_FLAGS before jax initializes (done here when the flag is unset).
+(DESIGN.md section 8). Under ``JAX_PLATFORMS=cpu`` the S host devices
+are forced via XLA_FLAGS before jax initializes (done here when the
+flag is unset); on an accelerator the mesh takes S of its devices.
+
+Compiled programs persist across runs (``repro.launch.compile_cache``).
 
 ``--mutate N`` appends an edge-churn replay (DESIGN.md section 7,
 EXPERIMENTS.md "Dynamic workloads"): N random insert/delete batches of
@@ -121,13 +124,17 @@ def main() -> None:
         ap.error("--mutate needs a writable fp32 index; quantized/"
                  "mmap'd artifacts are read-only")
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     mesh = None
     if args.mesh > 0:
-        # must land before jax initializes its backend (the imports
-        # above only define jitted functions, they run nothing)
-        os.environ.setdefault(
-            "XLA_FLAGS",
-            f"--xla_force_host_platform_device_count={args.mesh}")
+        if os.environ.get("JAX_PLATFORMS") == "cpu":
+            # must land before jax initializes its backend (the
+            # imports above only define jitted functions, they run
+            # nothing)
+            os.environ.setdefault(
+                "XLA_FLAGS",
+                f"--xla_force_host_platform_device_count={args.mesh}")
         from repro.core import shard_query
         mesh = shard_query.serving_mesh(args.mesh)
         print(f"mesh: {args.mesh}-way node-sharded serving over 'data'")

@@ -19,8 +19,8 @@ a traffic-serving system needs (README section "Serving"):
     latency;
   * **pluggable pair backend** -- the batched pair path runs either the
     vmapped searchsorted join (core/index.py) or the Pallas all-pairs
-    equality-join kernel (kernels/hp_join, DESIGN.md section 2) when a
-    compiled-Pallas backend is available;
+    equality-join kernel (kernels/hp_join, DESIGN.md section 2); "auto"
+    picks the kernel on a TPU and the searchsorted join elsewhere;
   * **node-sharded serving** -- with ``EngineConfig(mesh=...)`` the
     index partitions across the mesh axis and single-source/top-k
     queries dispatch through the shard_map fan-out
@@ -47,7 +47,6 @@ shapes it has already compiled.
 from __future__ import annotations
 
 import dataclasses
-import math
 import time
 from collections import OrderedDict
 
@@ -61,6 +60,7 @@ from repro.core.index import SlingIndex, _pair_query_batch
 from repro.core.single_source import batched_single_source, prune_tau
 from repro.core.topk import batched_topk
 from repro.graph import csr
+from repro.kernels import interpret_mode
 
 
 class _LRU:
@@ -108,10 +108,9 @@ class EngineConfig:
     pair_backend: str = "auto"   # "auto" | "join" | "pallas"
     # Horner-push backend for single-source/top-k (DESIGN.md §11):
     # "lax" | "pallas" | "auto" ("auto" defers to the process-wide
-    # switch in repro.kernels.horner_push, which itself defaults to
-    # pallas on TPU and lax elsewhere). Resolved once at engine
-    # construction so a long-lived engine never flips programs
-    # mid-traffic.
+    # switch in repro.kernels.horner_push, which itself resolves to
+    # lax on every platform). Resolved once at engine construction so
+    # a long-lived engine never flips programs mid-traffic.
     push_backend: str = "auto"
     # hot-swap shape stability (DESIGN.md section 7): device arrays are
     # padded to capacity buckets with this headroom, so a repaired
@@ -245,15 +244,6 @@ class QueryEngine:
             self._blk_dstl = jnp.asarray(bdl)
             self._blk_w = jnp.asarray(bw)
         self._tau = jnp.float32(prune_tau(index.plan))
-        if self._pair_backend == "pallas":
-            from repro.kernels.hp_join.ops import fold_sqrt_d
-            fk, fv = fold_sqrt_d(index)
-            fk2 = np.full((n, wc), INT32_PAD_KEY, np.int32)
-            fv2 = np.zeros((n, wc), np.float32)
-            fk2[:, :fk.shape[1]] = fk
-            fv2[:, :fv.shape[1]] = fv
-            self._folded_keys = jnp.asarray(fk2)
-            self._folded_vals = jnp.asarray(fv2)
         for a in (self._keys, self._vals, self._d, self._edge_src,
                   self._edge_dst, self._w):
             if a is not None:
@@ -434,13 +424,12 @@ class QueryEngine:
             u_b, v_b = us_p[lo:lo + B], vs_p[lo:lo + B]
             self._record("pair", (B, self._pair_backend))
             if self._pair_backend == "pallas":
-                from repro.kernels.hp_join.hp_join import hp_join
-                chunk = hp_join(self._folded_keys[u_b],
-                                self._folded_vals[u_b],
-                                self._folded_keys[v_b],
-                                self._folded_vals[v_b],
-                                bq=math.gcd(B, 8),
-                                interpret=jax.default_backend() != "tpu")
+                from repro.kernels.hp_join.ops import \
+                    pair_query_batch_pallas
+                chunk = pair_query_batch_pallas(
+                    self._keys, self._vals, self._d, jnp.asarray(u_b),
+                    jnp.asarray(v_b), n=self.index.n,
+                    interpret=interpret_mode())
             else:
                 chunk = _pair_query_batch(
                     self._keys, self._vals, self._d,
@@ -471,7 +460,7 @@ class QueryEngine:
                     jnp.asarray(us_p[lo:lo + B]), self._tau,
                     n=self.index.n, l_max=self.index.plan.l_max,
                     bn=self._pblk_bn, eb=self._pblk_eb,
-                    interpret=jax.default_backend() != "tpu"))
+                    interpret=interpret_mode()))
             else:
                 out[lo:lo + B] = np.asarray(batched_single_source(
                     self._keys, self._vals, self._d, self._edge_src,
@@ -503,7 +492,7 @@ class QueryEngine:
                     jnp.asarray(us_p[lo:lo + B]), self._tau,
                     self.index.n, self.index.plan.l_max, bucket,
                     self._pblk_bn, self._pblk_eb,
-                    interpret=jax.default_backend() != "tpu")
+                    interpret=interpret_mode())
             else:
                 v, i = batched_topk(
                     self._keys, self._vals, self._d, self._edge_src,

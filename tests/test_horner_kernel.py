@@ -253,6 +253,10 @@ def test_backend_switch_resolution():
         assert resolve_push_backend("lax") == "lax"
     with use_push_backend("lax"):
         assert resolve_push_backend(None) == "lax"
+    # "auto" never picks the fused kernel: it does not compile for TPU
+    with use_push_backend("auto"):
+        assert resolve_push_backend(None) == "lax"
+        assert resolve_push_backend("auto") == "lax"
     with pytest.raises(ValueError):
         resolve_push_backend("bogus")
     with pytest.raises(ValueError):

@@ -93,3 +93,33 @@ def test_flash_attention_vs_dense_sweep():
         g2 = jax.grad(lambda q: dense_ref(q, k, v, window, isg).sum())(q)
         np.testing.assert_allclose(np.asarray(g1), np.asarray(g2),
                                    atol=2e-5)
+
+
+def test_pallas_entry_points_have_no_interpret_default():
+    """Interpret mode is decided by repro.kernels.interpret_mode() at
+    the call site; an entry point that defaulted to interpret=True
+    would silently run the interpreter on the chip."""
+    import inspect
+
+    from repro.core import shard_query, single_source, topk
+    from repro.kernels.cin import cin, ops as cin_ops
+    from repro.kernels.horner_push import horner_push, ops as hp_ops
+    from repro.kernels.hp_join import hp_join, ops as join_ops
+    from repro.kernels.spmv_ell import ops as ell_ops, spmv_ell
+
+    modules = (cin, cin_ops, horner_push, hp_ops, hp_join, join_ops,
+               spmv_ell, ell_ops, single_source, topk, shard_query)
+    seen = 0
+    for mod in modules:
+        for name, fn in vars(mod).items():
+            if not callable(fn) or name.startswith("__"):
+                continue
+            try:
+                params = inspect.signature(fn).parameters
+            except (TypeError, ValueError):
+                continue
+            if "interpret" in params:
+                seen += 1
+                assert params["interpret"].default is \
+                    inspect.Parameter.empty, f"{mod.__name__}.{name}"
+    assert seen >= 8
