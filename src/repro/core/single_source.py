@@ -128,33 +128,34 @@ def horner_push(ku, xu, d, src, dst, w, tau, *, n: int, l_max: int,
     ``tau`` is the resolved prune threshold (:func:`prune_tau`).
     Returns (B, slab_size) float32 scores for the slab's nodes.
     """
-    B = ku.shape[0]
-    slab_size = n if slab_size is None else slab_size
-    d_offset = slab_start if d_offset is None else d_offset
-    ls = jnp.where(ku == INT32_PAD_KEY, -1, ku // n)
-    ks = jnp.clip(ku % n, 0, n - 1)
-    contrib = xu * d[jnp.clip(ks - d_offset, 0, d.shape[0] - 1)]
-    k_loc = ks - slab_start
-    in_slab = (k_loc >= 0) & (k_loc < slab_size)
-    k_loc = jnp.clip(k_loc, 0, slab_size - 1)
-    rows = jnp.arange(B, dtype=jnp.int32)[:, None]
+    with jax.named_scope("sling.push"):
+        B = ku.shape[0]
+        slab_size = n if slab_size is None else slab_size
+        d_offset = slab_start if d_offset is None else d_offset
+        ls = jnp.where(ku == INT32_PAD_KEY, -1, ku // n)
+        ks = jnp.clip(ku % n, 0, n - 1)
+        contrib = xu * d[jnp.clip(ks - d_offset, 0, d.shape[0] - 1)]
+        k_loc = ks - slab_start
+        in_slab = (k_loc >= 0) & (k_loc < slab_size)
+        k_loc = jnp.clip(k_loc, 0, slab_size - 1)
+        rows = jnp.arange(B, dtype=jnp.int32)[:, None]
 
-    def seed(l):
-        sel = jnp.where((ls == l) & in_slab, contrib, 0.0)    # (B, W)
-        z = jnp.zeros((B, slab_size), jnp.float32)
-        return z.at[rows, k_loc].add(sel)
+        def seed(l):
+            sel = jnp.where((ls == l) & in_slab, contrib, 0.0)  # (B, W)
+            z = jnp.zeros((B, slab_size), jnp.float32)
+            return z.at[rows, k_loc].add(sel)
 
-    def push(x):
-        xp = jnp.where(x > tau, x, 0.0)                       # (B, slab)
-        xg = xp if gather is None else gather(xp)             # (B, frontier)
-        msgs = xg[:, src] * w[None, :]                        # (B, E)
-        return jax.vmap(lambda mm: compat.segment_sum(
-            mm, dst, num_segments=slab_size))(msgs)
+        def push(x):
+            xp = jnp.where(x > tau, x, 0.0)                     # (B, slab)
+            xg = xp if gather is None else gather(xp)           # (B, frontier)
+            msgs = xg[:, src] * w[None, :]                      # (B, E)
+            return jax.vmap(lambda mm: compat.segment_sum(
+                mm, dst, num_segments=slab_size))(msgs)
 
-    acc = seed(l_max)
-    for l in range(l_max - 1, -1, -1):  # unrolled; l_max is static
-        acc = push(acc) + seed(l)
-    return acc
+        acc = seed(l_max)
+        for l in range(l_max - 1, -1, -1):  # unrolled; l_max is static
+            acc = push(acc) + seed(l)
+        return acc
 
 
 # ----------------------------------------------------------------------

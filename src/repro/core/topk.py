@@ -43,8 +43,9 @@ def batched_topk(keys, vals, d, edge_src, edge_dst, w, us, tau,
     """
     scores = batched_single_source(keys, vals, d, edge_src, edge_dst, w,
                                    us, tau, n=n, l_max=l_max)
-    top_v, top_i = jax.lax.top_k(scores, k)
-    return top_v, top_i.astype(jnp.int32)
+    with jax.named_scope("sling.select"):
+        top_v, top_i = jax.lax.top_k(scores, k)
+        return top_v, top_i.astype(jnp.int32)
 
 
 @partial(jax.jit,

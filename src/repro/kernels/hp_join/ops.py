@@ -33,15 +33,18 @@ def pair_query_batch_pallas(keys, vals, d, us, vs, *, n: int,
     arrays and (B,) float32 result. The sqrt(d_k) fold of ref.py is
     applied to the gathered (B, K) rows here, so the serving state
     needs no second, folded copy of the packed table."""
-    sd = jnp.sqrt(jnp.maximum(d, 0.0))
+    with jax.named_scope("sling.pair.fold"):
+        sd = jnp.sqrt(jnp.maximum(d, 0.0))
 
-    def fold(k, x):
-        return jnp.where(k == PAD, 0.0,
-                         x * sd[jnp.clip(k % n, 0, n - 1)])
+        def fold(k, x):
+            return jnp.where(k == PAD, 0.0,
+                             x * sd[jnp.clip(k % n, 0, n - 1)])
 
-    ku, kv = keys[us], keys[vs]
-    return hp_join(ku, fold(ku, vals[us]), kv, fold(kv, vals[vs]),
-                   interpret=interpret)
+        ku, kv = keys[us], keys[vs]
+        xu = fold(ku, vals[us])
+        xv = fold(kv, vals[vs])
+    with jax.named_scope("sling.pair.join"):
+        return hp_join(ku, xu, kv, xv, interpret=interpret)
 
 
 def query_pairs_kernel(index, us, vs, bq: int = 128) -> np.ndarray:

@@ -61,6 +61,7 @@ from repro.core.single_source import batched_single_source, prune_tau
 from repro.core.topk import batched_topk
 from repro.graph import csr
 from repro.kernels import interpret_mode
+from repro.serve import spans
 
 
 class _LRU:
@@ -413,28 +414,34 @@ class QueryEngine:
         key = "warmup_pad_slots" if self._in_warmup else "pad_slots"
         self._counts[key] += pad
 
+    def _pair_program(self, u_b: np.ndarray, v_b: np.ndarray):
+        """(jitted pair program, args, kwargs) for one id batch; the
+        ids are uploaded here."""
+        args = (self._keys, self._vals, self._d, jnp.asarray(u_b),
+                jnp.asarray(v_b))
+        if self._pair_backend == "pallas":
+            from repro.kernels.hp_join.ops import pair_query_batch_pallas
+            return pair_query_batch_pallas, args, {
+                "n": self.index.n, "interpret": interpret_mode()}
+        return _pair_query_batch, args + (self.index.n,), {}
+
     def _dispatch_pairs(self, us: np.ndarray, vs: np.ndarray) -> np.ndarray:
         B = self.cfg.pair_batch
-        pad = (-len(us)) % B
-        self._count_pad(pad)
-        us_p = np.concatenate([us, np.zeros(pad, np.int32)]).astype(np.int32)
-        vs_p = np.concatenate([vs, np.zeros(pad, np.int32)]).astype(np.int32)
+        with spans.span("sling.engine.pad"):
+            pad = (-len(us)) % B
+            self._count_pad(pad)
+            z = np.zeros(pad, np.int32)
+            us_p = np.concatenate([us, z]).astype(np.int32)
+            vs_p = np.concatenate([vs, z]).astype(np.int32)
+            calls = [self._pair_program(us_p[lo:lo + B], vs_p[lo:lo + B])
+                     for lo in range(0, len(us_p), B)]
         out = np.empty(len(us_p), np.float32)
-        for lo in range(0, len(us_p), B):
-            u_b, v_b = us_p[lo:lo + B], vs_p[lo:lo + B]
+        for c, (fn, args, kw) in enumerate(calls):
             self._record("pair", (B, self._pair_backend))
-            if self._pair_backend == "pallas":
-                from repro.kernels.hp_join.ops import \
-                    pair_query_batch_pallas
-                chunk = pair_query_batch_pallas(
-                    self._keys, self._vals, self._d, jnp.asarray(u_b),
-                    jnp.asarray(v_b), n=self.index.n,
-                    interpret=interpret_mode())
-            else:
-                chunk = _pair_query_batch(
-                    self._keys, self._vals, self._d,
-                    jnp.asarray(u_b), jnp.asarray(v_b), self.index.n)
-            out[lo:lo + B] = np.asarray(chunk)
+            with spans.span("sling.engine.launch"):
+                chunk = fn(*args, **kw)
+            with spans.span("sling.engine.sync"):
+                out[c * B:(c + 1) * B] = np.asarray(chunk)
         return out[:len(us)]
 
     def _dispatch_sources(self, us: np.ndarray) -> np.ndarray:
@@ -469,38 +476,44 @@ class QueryEngine:
                     l_max=self.index.plan.l_max))
         return out[:len(us)]
 
+    def _topk_program(self, u_b: np.ndarray, bucket: int):
+        """(top-k program, args, kwargs) for one id batch; the ids are
+        uploaded here, except on a mesh, whose fan-out places them."""
+        if self._sharded is not None:
+            from repro.core import shard_query
+            return shard_query.sharded_topk, (self._sharded, u_b, bucket), {
+                "backend": self._push_backend}
+        if self._push_backend == "pallas":
+            from repro.core.topk import batched_topk_pallas
+            return batched_topk_pallas, (
+                self._keys, self._vals, self._d, self._blk_src,
+                self._blk_dstl, self._blk_w, jnp.asarray(u_b), self._tau,
+                self.index.n, self.index.plan.l_max, bucket,
+                self._pblk_bn, self._pblk_eb), {
+                "interpret": interpret_mode()}
+        return batched_topk, (
+            self._keys, self._vals, self._d, self._edge_src,
+            self._edge_dst, self._w, jnp.asarray(u_b), self._tau,
+            self.index.n, self.index.plan.l_max, bucket), {}
+
     def _dispatch_topk(self, us: np.ndarray, bucket: int):
         B = self.cfg.source_batch
-        pad = (-len(us)) % B
-        self._count_pad(pad)
-        us_p = np.concatenate([us, np.full(pad, us[0] if len(us) else 0,
-                                           np.int32)]).astype(np.int32)
+        with spans.span("sling.engine.pad"):
+            pad = (-len(us)) % B
+            self._count_pad(pad)
+            us_p = np.concatenate([us, np.full(pad, us[0] if len(us) else 0,
+                                               np.int32)]).astype(np.int32)
+            calls = [self._topk_program(us_p[lo:lo + B], bucket)
+                     for lo in range(0, len(us_p), B)]
         sv = np.empty((len(us_p), bucket), np.float32)
         si = np.empty((len(us_p), bucket), np.int32)
-        for lo in range(0, len(us_p), B):
+        for c, (fn, args, kw) in enumerate(calls):
             self._record("topk", self._shape_tag(B, bucket))
-            if self._sharded is not None:
-                from repro.core import shard_query
-                v, i = shard_query.sharded_topk(
-                    self._sharded, us_p[lo:lo + B], bucket,
-                    backend=self._push_backend)
-            elif self._push_backend == "pallas":
-                from repro.core.topk import batched_topk_pallas
-                v, i = batched_topk_pallas(
-                    self._keys, self._vals, self._d, self._blk_src,
-                    self._blk_dstl, self._blk_w,
-                    jnp.asarray(us_p[lo:lo + B]), self._tau,
-                    self.index.n, self.index.plan.l_max, bucket,
-                    self._pblk_bn, self._pblk_eb,
-                    interpret=interpret_mode())
-            else:
-                v, i = batched_topk(
-                    self._keys, self._vals, self._d, self._edge_src,
-                    self._edge_dst, self._w, jnp.asarray(us_p[lo:lo + B]),
-                    self._tau, self.index.n, self.index.plan.l_max,
-                    bucket)
-            sv[lo:lo + B] = np.asarray(v)
-            si[lo:lo + B] = np.asarray(i)
+            with spans.span("sling.engine.launch"):
+                v, i = fn(*args, **kw)
+            with spans.span("sling.engine.sync"):
+                sv[c * B:(c + 1) * B] = np.asarray(v)
+                si[c * B:(c + 1) * B] = np.asarray(i)
         return sv[:len(us)], si[:len(us)]
 
     def _shape_tag(self, *shape):
@@ -517,27 +530,34 @@ class QueryEngine:
     # ------------------------------------------------------------------
     def pairs(self, us, vs) -> np.ndarray:
         """s(u_i, v_i) for aligned arrays of node ids."""
-        us = np.asarray(us, np.int32).ravel()
-        vs = np.asarray(vs, np.int32).ravel()
-        assert us.shape == vs.shape
-        self._counts["pair"] += len(us)
-        out = np.empty(len(us), np.float32)
-        miss_pos = []
-        for i, (u, v) in enumerate(zip(us.tolist(), vs.tolist())):
-            # s(u,v) = s(v,u): canonicalize so (v,u) hits a cached (u,v)
-            hit = self._cache.get(("pair", min(u, v), max(u, v)))
-            if hit is None:
-                miss_pos.append(i)
-            else:
-                out[i] = hit
-        if miss_pos:
-            got = self._dispatch_pairs(us[miss_pos], vs[miss_pos])
-            for j, i in enumerate(miss_pos):
-                out[i] = got[j]
-                u, v = int(us[i]), int(vs[i])
-                self._cache.put(("pair", min(u, v), max(u, v)),
-                                float(got[j]))
-        return out
+        with spans.span("sling.engine.pairs") as sp:
+            us = np.asarray(us, np.int32).ravel()
+            vs = np.asarray(vs, np.int32).ravel()
+            assert us.shape == vs.shape
+            self._counts["pair"] += len(us)
+            out = np.empty(len(us), np.float32)
+            miss_pos = []
+            with spans.span("sling.engine.cache"):
+                for i, (u, v) in enumerate(zip(us.tolist(), vs.tolist())):
+                    # s(u,v) = s(v,u): canonicalize so (v,u) hits a
+                    # cached (u,v)
+                    hit = self._cache.get(("pair", min(u, v), max(u, v)))
+                    if hit is None:
+                        miss_pos.append(i)
+                    else:
+                        out[i] = hit
+            if miss_pos:
+                got = self._dispatch_pairs(us[miss_pos], vs[miss_pos])
+                with spans.span("sling.engine.cache"):
+                    for j, i in enumerate(miss_pos):
+                        out[i] = got[j]
+                        u, v = int(us[i]), int(vs[i])
+                        self._cache.put(("pair", min(u, v), max(u, v)),
+                                        float(got[j]))
+            if sp:
+                sp.note(requests=len(us), misses=len(miss_pos),
+                        pad=(-len(miss_pos)) % self.cfg.pair_batch)
+            return out
 
     def pair(self, u: int, v: int) -> float:
         return float(self.pairs([u], [v])[0])
@@ -565,26 +585,32 @@ class QueryEngine:
     def topk(self, us, k: int) -> tuple[np.ndarray, np.ndarray]:
         """Top-k similar nodes per query: (Q, k') scores + node ids,
         k' = min(k, n), scores descending, ties toward small ids."""
-        us = np.atleast_1d(np.asarray(us, np.int32))
-        k_eff = min(int(k), self.index.n)
-        bucket = self._k_bucket(k_eff)
-        self._counts["topk"] += len(us)
-        sv = np.empty((len(us), k_eff), np.float32)
-        si = np.empty((len(us), k_eff), np.int32)
-        miss_pos = []
-        for i, u in enumerate(us.tolist()):
-            hit = self._cache.get(("topk", u, bucket))
-            if hit is None:
-                miss_pos.append(i)
-            else:
-                sv[i], si[i] = hit[0][:k_eff], hit[1][:k_eff]
-        if miss_pos:
-            gv, gi = self._dispatch_topk(us[miss_pos], bucket)
-            for j, i in enumerate(miss_pos):
-                sv[i], si[i] = gv[j, :k_eff], gi[j, :k_eff]
-                self._cache.put(("topk", int(us[i]), bucket),
-                                (gv[j].copy(), gi[j].copy()))
-        return sv, si
+        with spans.span("sling.engine.topk") as sp:
+            us = np.atleast_1d(np.asarray(us, np.int32))
+            k_eff = min(int(k), self.index.n)
+            bucket = self._k_bucket(k_eff)
+            self._counts["topk"] += len(us)
+            sv = np.empty((len(us), k_eff), np.float32)
+            si = np.empty((len(us), k_eff), np.int32)
+            miss_pos = []
+            with spans.span("sling.engine.cache"):
+                for i, u in enumerate(us.tolist()):
+                    hit = self._cache.get(("topk", u, bucket))
+                    if hit is None:
+                        miss_pos.append(i)
+                    else:
+                        sv[i], si[i] = hit[0][:k_eff], hit[1][:k_eff]
+            if miss_pos:
+                gv, gi = self._dispatch_topk(us[miss_pos], bucket)
+                with spans.span("sling.engine.cache"):
+                    for j, i in enumerate(miss_pos):
+                        sv[i], si[i] = gv[j, :k_eff], gi[j, :k_eff]
+                        self._cache.put(("topk", int(us[i]), bucket),
+                                        (gv[j].copy(), gi[j].copy()))
+            if sp:
+                sp.note(requests=len(us), misses=len(miss_pos),
+                        pad=(-len(miss_pos)) % self.cfg.source_batch)
+            return sv, si
 
     # ------------------------------------------------------------------
     # materialized kNN lookups (repro.join, DESIGN.md section 10)
@@ -667,6 +693,38 @@ class QueryEngine:
         finally:
             self._in_warmup = False
         return out
+
+    def program_texts(self) -> list[tuple[str, str]]:
+        """(name, optimized HLO text) of each pair and single-device
+        top-k program this engine has dispatched, the name being the
+        jitted function's. Each is lowered and compiled anew, past
+        JAX's persistent cache and its in-memory ones: the persistent
+        cache's key leaves debug info out, so a program it holds may
+        carry the ``op_name`` metadata (and so the
+        ``jax.named_scope``s) of an older version of the code. This
+        drops the process's compiled programs, so the next query
+        compiles (or loads) again: call it off the serving path."""
+        from jax.experimental.compilation_cache import compilation_cache
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        jax.clear_caches()
+        try:
+            out = []
+            for shape in sorted(self._shapes, key=repr):
+                z = np.zeros(shape[1], np.int32)
+                if shape[0] == "pair":
+                    fn, args, kw = self._pair_program(z, z)
+                elif shape[0] == "topk" and self._sharded is None:
+                    fn, args, kw = self._topk_program(z, shape[2])
+                else:
+                    continue
+                out.append((fn.__name__,
+                            fn.lower(*args, **kw).compile().as_text()))
+            return out
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+            compilation_cache.reset_cache()
 
     def stats(self) -> dict:
         return {

@@ -51,6 +51,7 @@ from collections import deque
 
 import numpy as np
 
+from repro.serve import spans
 from repro.serve.clock import MonotonicClock, VirtualClock
 from repro.serve.engine import EngineConfig, QueryEngine
 
@@ -78,6 +79,8 @@ class FrontendConfig:
 class Ticket:
     """Handle for one admitted request.
 
+    ``id`` is the request's admission number in its frontend (1, 2,
+    ...); a batch's span lists the ids of its requests.
     ``result()`` returns the query answer (pair -> float, source ->
     (n,) scores, topk -> (scores, ids)); it raises :class:`ShedError`
     if the deadline expired first. With the production clock it
@@ -86,12 +89,13 @@ class Ticket:
     instead of deadlocking a sleepless test).
     """
 
-    __slots__ = ("kind", "submit_t", "deadline", "fulfil_t", "shed",
+    __slots__ = ("kind", "id", "submit_t", "deadline", "fulfil_t", "shed",
                  "_value", "_event")
 
     def __init__(self, kind: str, submit_t: float,
-                 deadline: float | None):
+                 deadline: float | None, id: int = 0):
         self.kind = kind
+        self.id = id
         self.submit_t = submit_t
         self.deadline = deadline
         self.fulfil_t: float | None = None
@@ -155,6 +159,18 @@ class _Queue:
     timer_when: float = 0.0
 
 
+@dataclasses.dataclass
+class _Unit:
+    """A closed batch on its way to its replica."""
+    replica: int
+    key: tuple
+    items: list
+    reason: str
+    opened: float
+    closed: float
+    ahead: int                  # batches closed to the replica, not done
+
+
 @dataclasses.dataclass(frozen=True)
 class BatchRecord:
     """One dispatched batch (the epoch-purity / bound audit trail)."""
@@ -165,8 +181,9 @@ class BatchRecord:
     epoch: int
     replica: int
     reason: str                 # "size" | "wait" | "flush" | "swap"
-    opened: float
-    closed: float
+    opened: float               # first admission
+    closed: float               # the close (size, timer, flush or swap)
+    started: float              # its replica began to run it
 
 
 class ServeFrontend:
@@ -281,8 +298,8 @@ class ServeFrontend:
             if timeout is None:
                 timeout = self.cfg.default_timeout
             deadline = None if timeout is None else now + float(timeout)
-            ticket = Ticket(kind, now, deadline)
             self._counts["admitted"] += 1
+            ticket = Ticket(kind, now, deadline, self._counts["admitted"])
             if deadline is not None and deadline <= now:
                 self._counts["shed"] += 1
                 ticket._shed(now)
@@ -350,26 +367,27 @@ class ServeFrontend:
         q.items = keep
 
     def _on_timer(self, key: tuple) -> None:
-        unit = None
-        with self._lock:
-            q = self._queues.get(key)
-            if q is None:
-                return
-            q.timer = None
-            if not q.items:
-                return
-            self._shed_expired_locked(q)
-            if not q.items:
-                return
-            now = self.clock.now()
-            if self._swapping:
-                self._arm_timer_locked(key)
-            elif now >= q.open_since + self.cfg.max_wait - 1e-12:
-                unit = self._close_locked(key, "wait")
-            else:
-                self._arm_timer_locked(key)
-        if unit:
-            self._dispatch(unit)
+        with spans.span("sling.frontend.timer"):
+            unit = None
+            with self._lock:
+                q = self._queues.get(key)
+                if q is None:
+                    return
+                q.timer = None
+                if not q.items:
+                    return
+                self._shed_expired_locked(q)
+                if not q.items:
+                    return
+                now = self.clock.now()
+                if self._swapping:
+                    self._arm_timer_locked(key)
+                elif now >= q.open_since + self.cfg.max_wait - 1e-12:
+                    unit = self._close_locked(key, "wait")
+                else:
+                    self._arm_timer_locked(key)
+            if unit:
+                self._dispatch(unit)
 
     def _close_locked(self, key: tuple, reason: str):
         """Pop the open batch, shed expired members, pick a replica.
@@ -383,6 +401,7 @@ class ServeFrontend:
         q.items = []
         if not items:
             return None
+        closed = self.clock.now()
         loads = [self._inflight[r] for r in range(len(self.engines))]
         if self._mode == "thread":
             loads = [l + self._work[r].qsize()
@@ -392,21 +411,24 @@ class ServeFrontend:
             self._rr += 1
         else:
             replica = int(np.argmin(loads))
+        unit = _Unit(replica, key, items, reason, opened, closed,
+                     self._inflight[replica])
         self._inflight[replica] += 1
-        return (replica, key, items, reason, opened)
+        return unit
 
     # ------------------------------------------------------------------
     # dispatch
     # ------------------------------------------------------------------
-    def _dispatch(self, unit) -> None:
+    def _dispatch(self, unit: _Unit) -> None:
         if self._mode == "thread":
-            self._work[unit[0]].put(unit)
+            self._work[unit.replica].put(unit)
         else:
             self._run_unit(unit)
 
     def _worker(self, wq) -> None:
         while True:
-            unit = wq.get()
+            with spans.span("sling.worker.wait"):
+                unit = wq.get()
             if unit is None:
                 return
             try:
@@ -414,48 +436,55 @@ class ServeFrontend:
             except BaseException:           # keep the worker alive; the
                 self._fail_unit(unit)       # tickets surface the gap
 
-    def _fail_unit(self, unit) -> None:
-        replica, _key, items, _reason, _opened = unit
+    def _fail_unit(self, unit: _Unit) -> None:
         now = self.clock.now()
-        for r in items:
+        for r in unit.items:
             if not r.ticket.done():
                 r.ticket._shed(now)
         with self._lock:
-            self._counts["shed"] += len(items)
-            self._inflight[replica] -= 1
+            self._counts["shed"] += len(unit.items)
+            self._inflight[unit.replica] -= 1
             self._idle.notify_all()
 
-    def _run_unit(self, unit) -> None:
-        replica, key, items, reason, opened = unit
-        eng = self.engines[replica]
-        kind = key[0]
-        t0 = self.clock.now()
-        epoch = self._epoch
-        us = np.asarray([r.u for r in items], np.int32)
-        if kind == "pair":
-            vs = np.asarray([r.v for r in items], np.int32)
-            vals = eng.pairs(us, vs)
-            results = [float(v) for v in vals]
-        elif kind == "source":
-            rows = eng.single_source(us)
-            results = [rows[i].copy() for i in range(len(items))]
-        else:
-            sv, si = eng.topk(us, key[1])
-            results = [(sv[i].copy(), si[i].copy())
-                       for i in range(len(items))]
-        t1 = self.clock.now()
-        for r, val in zip(items, results):
-            r.ticket._fulfil(val, t1)
-        with self._lock:
-            self._counts["served"] += len(items)
-            self._counts["batches"] += 1
-            self._occ_sum += len(items) / self.cfg.cap(kind)
-            self.batch_log.append(BatchRecord(
-                kind=kind, key=key, size=len(items),
-                cap=self.cfg.cap(kind), epoch=epoch, replica=replica,
-                reason=reason, opened=opened, closed=t0))
-            self._inflight[replica] -= 1
-            self._idle.notify_all()
+    def _run_unit(self, unit: _Unit) -> None:
+        items, kind = unit.items, unit.key[0]
+        with spans.span("sling.frontend.batch") as sp:
+            if sp:
+                sp.note(kind=kind, size=len(items), cap=self.cfg.cap(kind),
+                        reason=unit.reason, replica=unit.replica,
+                        ahead=unit.ahead, closed=unit.closed,
+                        requests=tuple(r.ticket.id for r in items))
+            eng = self.engines[unit.replica]
+            t0 = self.clock.now()
+            epoch = self._epoch
+            us = np.asarray([r.u for r in items], np.int32)
+            if kind == "pair":
+                vs = np.asarray([r.v for r in items], np.int32)
+                vals = eng.pairs(us, vs)
+                results = [float(v) for v in vals]
+            elif kind == "source":
+                rows = eng.single_source(us)
+                results = [rows[i].copy() for i in range(len(items))]
+            else:
+                sv, si = eng.topk(us, unit.key[1])
+                results = [(sv[i].copy(), si[i].copy())
+                           for i in range(len(items))]
+            with spans.span("sling.frontend.fulfil"):
+                t1 = self.clock.now()
+                for r, val in zip(items, results):
+                    r.ticket._fulfil(val, t1)
+                with self._lock:
+                    self._counts["served"] += len(items)
+                    self._counts["batches"] += 1
+                    self._occ_sum += len(items) / self.cfg.cap(kind)
+                    self.batch_log.append(BatchRecord(
+                        kind=kind, key=unit.key, size=len(items),
+                        cap=self.cfg.cap(kind), epoch=epoch,
+                        replica=unit.replica, reason=unit.reason,
+                        opened=unit.opened, closed=unit.closed,
+                        started=t0))
+                    self._inflight[unit.replica] -= 1
+                    self._idle.notify_all()
 
     # ------------------------------------------------------------------
     # control plane
