@@ -24,7 +24,10 @@ Every device path -- single-device batched, the model-axis pod push,
 and the node-sharded serving fan-out (core/shard_query.py) -- runs the
 same :func:`horner_push` kernel over a node *slab*; the single-device
 case is simply the slab that covers all n nodes with an identity
-frontier gather.
+frontier gather. One device may instead run :func:`horner_push_dense`,
+the same push as an exact float32 product with a dense bfloat16
+operator on the MXU, where the graph is small and dense enough for
+that to be cheaper (``serve.engine.dense_push_fits``).
 """
 from __future__ import annotations
 
@@ -159,6 +162,99 @@ def horner_push(ku, xu, d, src, dst, w, tau, *, n: int, l_max: int,
 
 
 # ----------------------------------------------------------------------
+# the dense twin: the same Horner push as an exact float32 MXU product
+# ----------------------------------------------------------------------
+DENSE_LANE = 128            # the dense operator's side is a multiple of this
+DENSE_MAX_MULTIPLICITY = 256  # largest edge count bfloat16 holds exactly
+
+
+def dense_side(n: int) -> int:
+    """Side n_pad of the dense push operator: n rounded up to a lane
+    multiple."""
+    return -(-n // DENSE_LANE) * DENSE_LANE
+
+
+def _edge_counts(g: csr.Graph) -> tuple[np.ndarray, np.ndarray]:
+    """Distinct edges as u * n + v, and how many times each occurs."""
+    return np.unique(g.edge_src.astype(np.int64) * g.n + g.edge_dst,
+                     return_counts=True)
+
+
+def max_edge_multiplicity(g: csr.Graph) -> int:
+    """Largest number of parallel edges u -> v in ``g`` (0 if none)."""
+    return int(_edge_counts(g)[1].max()) if g.m else 0
+
+
+def dense_push_operator(g: csr.Graph, sqrt_c: float,
+                        n_pad: int) -> tuple[np.ndarray, np.ndarray]:
+    """Host arrays of the dense push: ``adjT`` (n_pad, n_pad) bfloat16,
+    ``adjT[u, v]`` the number of edges u -> v, and ``s`` (n_pad,)
+    float32, sqrt(c)/|I(v)| (0 where I(v) is empty or v is padding),
+    so that A_hat x = (x @ adjT) * s. Counts up to
+    :data:`DENSE_MAX_MULTIPLICITY` are exact in bfloat16; ``s`` rounds
+    as :func:`~repro.graph.csr.normalized_pull_weights` does."""
+    pair, count = _edge_counts(g)
+    if g.m and count.max() > DENSE_MAX_MULTIPLICITY:
+        raise ValueError("an edge multiplicity above "
+                         f"{DENSE_MAX_MULTIPLICITY} is not exact in bfloat16")
+    adjT = np.zeros((n_pad, n_pad), jnp.bfloat16)
+    adjT[pair // g.n, pair % g.n] = count
+    deg = g.in_deg
+    s = np.zeros(n_pad, np.float32)
+    s[:g.n] = np.where(deg > 0, sqrt_c / np.maximum(deg, 1.0), 0.0)
+    return adjT, s
+
+
+def split_bf16x3(x):
+    """(3B, m) bfloat16 parts hi, mid, lo of float32 ``x`` (B, m) whose
+    float32 sum is ``x``: each part keeps the next 8 significant bits.
+    The rounding is ``reduce_precision`` in float32, which XLA keeps,
+    where a float32 -> bfloat16 -> float32 convert pair may be folded
+    away under excess precision and zero the residuals; the final
+    cast is exact."""
+    hi = jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
+    r = x - hi
+    mid = jax.lax.reduce_precision(r, exponent_bits=8, mantissa_bits=7)
+    return jnp.concatenate([hi, mid, r - mid]).astype(jnp.bfloat16)
+
+
+def horner_push_dense(ku, xu, d, adjT, s, tau, *, n: int, l_max: int):
+    """:func:`horner_push` on one device with a dense operator.
+
+    ``adjT``/``s`` from :func:`dense_push_operator` (on the device);
+    ku/xu, ``d`` and ``tau`` as for :func:`horner_push`. All l_max + 1
+    seed levels come from one scatter into an (l_max + 1, B, n_pad)
+    array. Each step multiplies the pruned frontier's three bfloat16
+    parts (:func:`split_bf16x3`) by the bfloat16 counts on the MXU,
+    accumulating in float32: every product is exact, so the step is a
+    float32 evaluation of A_hat x, which differs from the scatter's
+    only in summation order. Returns (B, n) float32 scores.
+    """
+    with jax.named_scope("sling.push"):
+        B = ku.shape[0]
+        n_pad = adjT.shape[0]
+        levels = l_max + 1
+        # pad keys (and any level past l_max) fall outside: dropped
+        ls = jnp.where(ku == INT32_PAD_KEY, levels, ku // n)
+        ks = jnp.clip(ku % n, 0, n - 1)
+        rows = jnp.broadcast_to(jnp.arange(B, dtype=jnp.int32)[:, None],
+                                ku.shape)
+        seeds = jnp.zeros((levels, B, n_pad), jnp.float32).at[
+            ls, rows, ks].add(xu * d[ks], mode="drop")
+
+        def push(x):
+            xp = jnp.where(x > tau, x, 0.0)                     # (B, n_pad)
+            y = jax.lax.dot(split_bf16x3(xp), adjT,
+                            preferred_element_type=jnp.float32)  # (3B, n_pad)
+            return (y[:B] + y[B:2 * B] + y[2 * B:]) * s
+
+        acc = seeds[l_max]
+        for l in range(l_max - 1, -1, -1):  # unrolled; l_max is static
+            acc = push(acc) + seeds[l]
+        return acc[:, :n]
+
+
+# ----------------------------------------------------------------------
 # batched device path: (B,) query nodes -> (B, n) scores
 # ----------------------------------------------------------------------
 @partial(jax.jit, static_argnames=("n", "l_max"))
@@ -172,6 +268,16 @@ def batched_single_source(keys, vals, d, edge_src, edge_dst, w,
     """
     return horner_push(keys[us], vals[us], d, edge_src, edge_dst, w,
                        tau, n=n, l_max=l_max)
+
+
+@partial(jax.jit, static_argnames=("n", "l_max"))
+def batched_single_source_dense(keys, vals, d, adjT, s, us, tau,
+                                n: int, l_max: int):
+    """Dense-operator twin of :func:`batched_single_source`
+    (:func:`horner_push_dense`); takes ``adjT``/``s`` in place of the
+    edge arrays. Returns (B, n) float32."""
+    return horner_push_dense(keys[us], vals[us], d, adjT, s, tau,
+                             n=n, l_max=l_max)
 
 
 @partial(jax.jit, static_argnames=("n", "l_max", "bn", "eb", "interpret"))

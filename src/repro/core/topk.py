@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.single_source import (batched_single_source,
+                                      batched_single_source_dense,
                                       batched_single_source_pallas,
                                       single_source_paper)
 from repro.graph import csr
@@ -43,6 +44,20 @@ def batched_topk(keys, vals, d, edge_src, edge_dst, w, us, tau,
     """
     scores = batched_single_source(keys, vals, d, edge_src, edge_dst, w,
                                    us, tau, n=n, l_max=l_max)
+    with jax.named_scope("sling.select"):
+        top_v, top_i = jax.lax.top_k(scores, k)
+        return top_v, top_i.astype(jnp.int32)
+
+
+@partial(jax.jit, static_argnames=("n", "l_max", "k"))
+def batched_topk_dense(keys, vals, d, adjT, s, us, tau,
+                       n: int, l_max: int, k: int):
+    """Dense-operator twin of :func:`batched_topk`: the push is
+    :func:`~repro.core.single_source.horner_push_dense` over the
+    device-resident ``adjT``/``s``; selection, transfer and
+    tie-breaking are the same."""
+    scores = batched_single_source_dense(keys, vals, d, adjT, s, us, tau,
+                                         n=n, l_max=l_max)
     with jax.named_scope("sling.select"):
         top_v, top_i = jax.lax.top_k(scores, k)
         return top_v, top_i.astype(jnp.int32)

@@ -17,6 +17,14 @@ a traffic-serving system needs (README section "Serving"):
   * **warmup priming** -- ``warmup()`` compiles every fixed shape ahead
     of traffic so the first real request is served at steady-state
     latency;
+  * **push path from the graph's shape** -- on one device with the lax
+    push, single-source and top-k run the Horner push either as the
+    per-edge scatter or as an exact float32 product with a dense
+    bfloat16 operator on the MXU (core/single_source.py), whichever
+    the graph's node count, edge capacity and edge multiplicity make
+    cheaper (:func:`dense_push_fits`, DESIGN.md section 11); the
+    choice is made once, at construction, and ``stats()["push_path"]``
+    reports it;
   * **pluggable pair backend** -- the batched pair path runs either the
     vmapped searchsorted join (core/index.py) or the Pallas all-pairs
     equality-join kernel (kernels/hp_join, DESIGN.md section 2); "auto"
@@ -57,11 +65,47 @@ import numpy as np
 from repro.core import hp_index
 from repro.core.hp_index import INT32_PAD_KEY
 from repro.core.index import SlingIndex, _pair_query_batch
-from repro.core.single_source import batched_single_source, prune_tau
-from repro.core.topk import batched_topk
+from repro.core.single_source import (DENSE_MAX_MULTIPLICITY,
+                                      batched_single_source,
+                                      batched_single_source_dense,
+                                      dense_push_operator, dense_side,
+                                      max_edge_multiplicity, prune_tau)
+from repro.core.topk import batched_topk, batched_topk_dense
 from repro.graph import csr
 from repro.kernels import interpret_mode
 from repro.serve import spans
+
+
+# The dense push reads n_pad**2 operator cells a step where the scatter
+# walks edge_cap edge slots. Per platform, the cells per edge slot at
+# or below which the engine takes the dense push: half the crossover
+# measured with scripts/push_crossover.py (on a v5e, 4,462 where the
+# operator streams from HBM; PERF.md), a power of two. A platform with
+# no measured crossover keeps the scatter.
+DENSE_PUSH_CELLS_PER_EDGE = {"tpu": 2048}
+# the dense operator may take at most this share of the device memory
+DENSE_PUSH_MEMORY_SHARE = 1 / 16
+# device memory assumed where the device reports none (a v5e chip)
+DEFAULT_DEVICE_BYTES = 16 * 2**30
+
+
+def dense_push_fits(n: int, edge_cap: int, max_multiplicity: int,
+                    platform: str, device_bytes: int) -> bool:
+    """Whether the dense push serves a graph of ``n`` nodes, ``edge_cap``
+    padded edge slots and at most ``max_multiplicity`` parallel edges on
+    one device of ``platform`` with ``device_bytes`` of memory: it is
+    the cheaper push there, its bfloat16 operator takes a small share of
+    the memory, and the counts are exact in bfloat16."""
+    n_pad = dense_side(n)
+    return (n_pad * n_pad <= DENSE_PUSH_CELLS_PER_EDGE.get(platform, 0)
+            * edge_cap
+            and 2 * n_pad * n_pad <= DENSE_PUSH_MEMORY_SHARE * device_bytes
+            and max_multiplicity <= DENSE_MAX_MULTIPLICITY)
+
+
+def _device_bytes() -> int:
+    stats = jax.devices()[0].memory_stats() or {}
+    return int(stats.get("bytes_limit", DEFAULT_DEVICE_BYTES))
 
 
 class _LRU:
@@ -178,6 +222,7 @@ class QueryEngine:
         self._shard_edge_cap = 0     # set by the first sharded install
         self._pblk_cap = 0           # pallas blocked-layout width bucket
         self._shard_pblk_cap = 0
+        self._push_path = self._choose_push_path(g)
         self._install(index, g)
         assert index.n >= 1
 
@@ -188,6 +233,18 @@ class QueryEngine:
         return hp_index.capacity_bucket(x, self.cfg.cap_quantum,
                                         self.cfg.swap_headroom)
 
+    def _choose_push_path(self, g: csr.Graph) -> str:
+        """The push path, "dense" or "sparse" (:func:`dense_push_fits`);
+        mesh engines and the Pallas backend keep the scatter."""
+        if self.cfg.mesh is not None or self._push_backend != "lax":
+            return "sparse"
+        # the shape first: the multiplicity costs a sort of the edges
+        if not dense_push_fits(g.n, self._edge_cap, 0,
+                               jax.default_backend(), _device_bytes()):
+            return "sparse"
+        return ("dense" if max_edge_multiplicity(g) <= DENSE_MAX_MULTIPLICITY
+                else "sparse")
+
     def _install(self, index: SlingIndex, g: csr.Graph) -> None:
         """Upload ``index``/``g`` padded to the capacity buckets.
 
@@ -196,7 +253,10 @@ class QueryEngine:
         -- keys/vals (n, width_cap), d (n,), edges (edge_cap,). Pad
         rows carry the INT32_PAD_KEY sentinel (ignored by every join)
         and pad edges carry weight 0 into segment 0 (additive no-op in
-        every push), so padded and exact dispatch agree bit-for-bit.
+        every push), so padded and exact dispatch agree bit-for-bit. On
+        the dense push path the edges become the (n_pad, n_pad)
+        bfloat16 operator and its (n_pad,) scale, whose shapes depend on
+        n alone.
         """
         n = index.n
         wc, ec = self._width_cap, self._edge_cap
@@ -211,7 +271,13 @@ class QueryEngine:
         self._keys = jnp.asarray(keys)
         self._vals = jnp.asarray(vals)
         self._d = jnp.asarray(np.asarray(index.d, np.float32))
-        if self.cfg.mesh is None:
+        self._adjT = self._s = None
+        if self._push_path == "dense":
+            adjT, s = dense_push_operator(g, index.plan.sqrt_c,
+                                          dense_side(index.n))
+            self._adjT, self._s = jnp.asarray(adjT), jnp.asarray(s)
+            self._edge_src = self._edge_dst = self._w = None
+        elif self.cfg.mesh is None:
             e_src = np.zeros(ec, np.int32)
             e_dst = np.zeros(ec, np.int32)
             e_w = np.zeros(ec, np.float32)
@@ -246,7 +312,7 @@ class QueryEngine:
             self._blk_w = jnp.asarray(bw)
         self._tau = jnp.float32(prune_tau(index.plan))
         for a in (self._keys, self._vals, self._d, self._edge_src,
-                  self._edge_dst, self._w):
+                  self._edge_dst, self._w, self._adjT, self._s):
             if a is not None:
                 a.block_until_ready()
         # node-sharded serving state: rebuilt with the same capacity
@@ -309,12 +375,19 @@ class QueryEngine:
         if index.hp.width > self._width_cap:
             self._width_cap = self._bucket(index.hp.width)
             recompiles += 1
+        if self._push_path == "dense" \
+                and max_edge_multiplicity(g) > DENSE_MAX_MULTIPLICITY:
+            # counts past bfloat16's exact range: back to the scatter
+            self._push_path = "sparse"
+            recompiles += 1
         if self._sharded is None and g.m > self._edge_cap:
             # single-device mode only: in mesh mode no compiled
             # program closes over the (unbuilt) total-edge bucket --
-            # the per-shard check below is the real one
+            # the per-shard check below is the real one; the dense
+            # operator's shape does not depend on it
             self._edge_cap = self._bucket(g.m)
-            recompiles += 1
+            if self._push_path == "sparse":
+                recompiles += 1
         if self._sharded is not None:
             # a shifted edge distribution can overflow one shard's
             # block even when the total m still fits its bucket
@@ -458,6 +531,11 @@ class QueryEngine:
                 out[lo:lo + B] = shard_query.sharded_single_source(
                     self._sharded, us_p[lo:lo + B],
                     backend=self._push_backend)
+            elif self._push_path == "dense":
+                out[lo:lo + B] = np.asarray(batched_single_source_dense(
+                    self._keys, self._vals, self._d, self._adjT, self._s,
+                    jnp.asarray(us_p[lo:lo + B]), self._tau,
+                    n=self.index.n, l_max=self.index.plan.l_max))
             elif self._push_backend == "pallas":
                 from repro.core.single_source import \
                     batched_single_source_pallas
@@ -483,6 +561,11 @@ class QueryEngine:
             from repro.core import shard_query
             return shard_query.sharded_topk, (self._sharded, u_b, bucket), {
                 "backend": self._push_backend}
+        if self._push_path == "dense":
+            return batched_topk_dense, (
+                self._keys, self._vals, self._d, self._adjT, self._s,
+                jnp.asarray(u_b), self._tau, self.index.n,
+                self.index.plan.l_max, bucket), {}
         if self._push_backend == "pallas":
             from repro.core.topk import batched_topk_pallas
             return batched_topk_pallas, (
@@ -517,13 +600,13 @@ class QueryEngine:
         return sv[:len(us)], si[:len(us)]
 
     def _shape_tag(self, *shape):
-        """Dispatch-shape key; sharded programs and the two push
-        backends are distinct compiled programs, hence distinct
-        shapes."""
+        """Dispatch-shape key; sharded programs, the two push backends
+        and the two push paths are distinct compiled programs, hence
+        distinct shapes. The path comes last."""
         shape = shape + (self._push_backend,)
         if self._sharded is not None:
-            return shape + ("mesh", self._sharded.n_shards)
-        return shape
+            shape = shape + ("mesh", self._sharded.n_shards)
+        return shape + (self._push_path,)
 
     # ------------------------------------------------------------------
     # public API
@@ -564,23 +647,29 @@ class QueryEngine:
 
     def single_source(self, us) -> np.ndarray:
         """(Q, n) scores for an array of query nodes."""
-        us = np.atleast_1d(np.asarray(us, np.int32))
-        self._counts["source"] += len(us)
-        out = np.empty((len(us), self.index.n), np.float32)
-        miss_pos = []
-        for i, u in enumerate(us.tolist()):
-            hit = self._cache.get(("src", u))
-            if hit is None:
-                miss_pos.append(i)
-            else:
-                out[i] = hit
-        if miss_pos:
-            got = self._dispatch_sources(us[miss_pos])
-            for j, i in enumerate(miss_pos):
-                out[i] = got[j]
-                # copy: got[j] is a view retaining the whole padded batch
-                self._cache.put(("src", int(us[i])), got[j].copy())
-        return out
+        with spans.span("sling.engine.sources") as sp:
+            us = np.atleast_1d(np.asarray(us, np.int32))
+            self._counts["source"] += len(us)
+            out = np.empty((len(us), self.index.n), np.float32)
+            miss_pos = []
+            for i, u in enumerate(us.tolist()):
+                hit = self._cache.get(("src", u))
+                if hit is None:
+                    miss_pos.append(i)
+                else:
+                    out[i] = hit
+            if miss_pos:
+                got = self._dispatch_sources(us[miss_pos])
+                for j, i in enumerate(miss_pos):
+                    out[i] = got[j]
+                    # copy: got[j] is a view retaining the whole padded
+                    # batch
+                    self._cache.put(("src", int(us[i])), got[j].copy())
+            if sp:
+                sp.note(requests=len(us), misses=len(miss_pos),
+                        pad=(-len(miss_pos)) % self.cfg.source_batch,
+                        push=self._push_path)
+            return out
 
     def topk(self, us, k: int) -> tuple[np.ndarray, np.ndarray]:
         """Top-k similar nodes per query: (Q, k') scores + node ids,
@@ -609,7 +698,8 @@ class QueryEngine:
                                         (gv[j].copy(), gi[j].copy()))
             if sp:
                 sp.note(requests=len(us), misses=len(miss_pos),
-                        pad=(-len(miss_pos)) % self.cfg.source_batch)
+                        pad=(-len(miss_pos)) % self.cfg.source_batch,
+                        push=self._push_path)
             return sv, si
 
     # ------------------------------------------------------------------
@@ -741,6 +831,7 @@ class QueryEngine:
             "unique_shapes": sorted(self._shapes),
             "pair_backend": self._pair_backend,
             "push_backend": self._push_backend,
+            "push_path": self._push_path,
             "quantized": (self.index.quant.scheme
                           if self.index.quant is not None else None),
             "mesh_shards": (self._sharded.n_shards

@@ -22,8 +22,10 @@ The spans (see serve/frontend.py and serve/engine.py):
 * ``sling.frontend.batch``: one batch on its replica (attrs: kind, size,
   cap, reason, replica, ahead, closed, requests);
 * ``sling.frontend.fulfil``: its tickets fulfilled and ``batch_log``;
-* ``sling.engine.pairs`` / ``sling.engine.topk``: one engine call
-  (attrs: requests, misses, pad);
+* ``sling.engine.pairs`` / ``sling.engine.topk`` /
+  ``sling.engine.sources``: one engine call (attrs: requests, misses,
+  pad; top-k and sources also ``push``, the engine's push path,
+  "dense" or "sparse");
 * ``sling.engine.cache``: LRU lookups or puts;
 * ``sling.engine.pad``: padding and upload of the id batches;
 * ``sling.engine.launch``: the jitted call until it returns (attr

@@ -326,3 +326,144 @@ def test_k_bucketing_shares_programs(engine):
     assert len(engine.stats()["unique_shapes"]) == n_shapes
     sv, si = engine.topk([4], 9)
     assert sv.shape == (1, 9)
+
+
+# ----------------------------------------------------------------------
+# push path: dense MXU product or per-edge scatter, from the graph's shape
+# ----------------------------------------------------------------------
+V5E_BYTES = 16 * 10**9
+
+
+@pytest.mark.parametrize("n,m,mult,platform,want", [
+    (7115, 103689, 1, "tpu", True),          # wiki-Vote's shape
+    (10**6, 3 * 10**6, 1, "tpu", False),     # the 10^6-node bring-up
+    (20000, 20000, 1, "tpu", False),         # past the crossover
+    (30000, 2 * 10**6, 1, "tpu", False),     # operator over 1/16 of HBM
+    (7115, 103689, 257, "tpu", False),       # counts past bfloat16
+    (7115, 103689, 256, "tpu", True),
+    (7115, 103689, 1, "cpu", False),         # no measured crossover
+])
+def test_push_path_rule_on_described_shapes(n, m, mult, platform, want):
+    from repro.core.hp_index import capacity_bucket
+    from repro.serve.engine import dense_push_fits
+    assert dense_push_fits(n, capacity_bucket(m), mult, platform,
+                           V5E_BYTES) is want
+
+
+@pytest.fixture()
+def dense_on_cpu(monkeypatch):
+    """Give the CPU the TPU's crossover, so that the engine's dense
+    path runs here."""
+    from repro.serve import engine as engine_mod
+    monkeypatch.setitem(engine_mod.DENSE_PUSH_CELLS_PER_EDGE, "cpu",
+                        engine_mod.DENSE_PUSH_CELLS_PER_EDGE["tpu"])
+
+
+def test_cpu_engine_keeps_the_scatter(small_graph, sling_index):
+    eng = QueryEngine(sling_index, small_graph)
+    assert eng.stats()["push_path"] == "sparse"
+
+
+def test_dense_engine_matches_the_scatter_engine(small_graph, sling_index,
+                                                 dense_on_cpu):
+    cfg = EngineConfig(source_batch=4, cache_size=0, push_backend="lax")
+    dense = QueryEngine(sling_index, small_graph, cfg)
+    assert dense.stats()["push_path"] == "dense"
+    us = np.array([0, 7, 42, 99, 149], np.int32)
+    ref = single_source_device(sling_index, small_graph, us, backend="lax")
+    got = dense.single_source(us)
+    assert np.abs(got - ref).max() <= 1e-6 * np.abs(ref).max()
+    sv, si = dense.topk(us, 10)
+    for i in range(len(us)):
+        np.testing.assert_allclose(sv[i], ref[i][si[i]], atol=1e-6)
+        np.testing.assert_allclose(sv[i], np.sort(ref[i])[::-1][:10],
+                                   atol=1e-6)
+    shapes = dense.stats()["unique_shapes"]
+    assert {s[-1] for s in shapes if s[0] in ("topk", "source")} == {"dense"}
+    assert all(s[1] == 4 for s in shapes if s[0] == "topk")
+    fn, _, _ = dense._topk_program(us[:4], 16)
+    assert "topk" in fn.__name__
+
+
+def test_dense_path_swap_compiles_nothing(small_graph, dense_on_cpu):
+    """The operator keeps its (n_pad, n_pad) shape across a swap: after
+    warm-up, swaps and traffic compile no new program."""
+    from repro.core.single_source import batched_single_source_dense
+    from repro.core.topk import batched_topk_dense
+    g = small_graph
+    idx = _fresh_index(g)
+    eng = QueryEngine(idx, g, EngineConfig(pair_batch=16, source_batch=4))
+    assert eng.stats()["push_path"] == "dense"
+    eng.warmup()
+    before = set(eng.stats()["unique_shapes"])
+    programs = (batched_topk_dense._cache_size(),
+                batched_single_source_dense._cache_size())
+    rng = np.random.default_rng(5)
+    for i in range(2):
+        rep = _churn(g, idx, seed=40 + i)
+        g = rep.graph
+        eng.swap_index(idx, g, affected=rep.affected)
+        us = rng.integers(0, idx.n, 5).astype(np.int32)
+        eng.single_source(us)
+        sv, si = eng.topk(us, 7)
+        ref = single_source_device(idx, g, us, backend="lax")
+        for j in range(len(us)):
+            np.testing.assert_allclose(sv[j], ref[j][si[j]], atol=1e-6)
+    st = eng.stats()
+    assert set(st["unique_shapes"]) == before
+    assert st["swap_recompiles"] == 0 and st["push_path"] == "dense"
+    assert (batched_topk_dense._cache_size(),
+            batched_single_source_dense._cache_size()) == programs
+
+
+def test_multiplicity_past_bfloat16_keeps_the_scatter(dense_on_cpu):
+    from repro.core import build
+    from repro.graph import csr
+    rng = np.random.default_rng(2)
+    src = np.concatenate([rng.integers(0, 40, 200), np.zeros(257, int)])
+    dst = np.concatenate([rng.integers(0, 40, 200), np.ones(257, int)])
+    keep = src != dst
+    g = csr.from_edges(40, src[keep], dst[keep], dedup=False)
+    idx = build.build_index(g, eps=0.2, exact_d=True, seed=0)
+    assert QueryEngine(idx, g).stats()["push_path"] == "sparse"
+    g1 = csr.from_edges(40, src[keep], dst[keep])
+    eng = QueryEngine(idx, g1)
+    assert eng.stats()["push_path"] == "dense"
+    # a swap that brings such counts falls back to the scatter, counted
+    out = eng.swap_index(idx, g)
+    assert out["recompiles"] >= 1
+    assert eng.stats()["push_path"] == "sparse"
+    us = np.array([0, 1, 5], np.int32)
+    np.testing.assert_allclose(
+        eng.single_source(us),
+        single_source_device(idx, g, us, backend="lax"), atol=1e-6)
+
+
+def test_mesh_and_pallas_engines_keep_the_scatter(small_graph, sling_index,
+                                                  dense_on_cpu):
+    from repro.core import shard_query
+    mesh = shard_query.serving_mesh(1)
+    for cfg in (EngineConfig(mesh=mesh), EngineConfig(push_backend="pallas")):
+        eng = QueryEngine(sling_index, small_graph, cfg)
+        assert eng.stats()["push_path"] == "sparse"
+
+
+def test_engine_spans_name_the_push_path(small_graph, sling_index,
+                                         dense_on_cpu):
+    import time
+
+    from repro.serve import spans
+    eng = QueryEngine(sling_index, small_graph,
+                      EngineConfig(source_batch=4, k_buckets=(4,)))
+    rec = spans.Recorder(time.monotonic)
+    spans.enable(rec)
+    try:
+        eng.topk([1, 2], 4)
+        eng.single_source([3])
+    finally:
+        spans.disable()
+    calls = {r[0]: r[6] for r in rec.records
+             if r[0] in ("sling.engine.topk", "sling.engine.sources")}
+    assert set(calls) == {"sling.engine.topk", "sling.engine.sources"}
+    assert all(a["push"] == "dense" for a in calls.values())
+    assert calls["sling.engine.sources"]["requests"] == 1

@@ -109,3 +109,34 @@ def test_hp_join_compiles_for_v5e(one_chip):
         interpret=False).compile()
     _fits_hbm(compiled)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# wiki-Vote's widths (bench/configs/wikivote-e0.025.json): n = 7,115
+# padded to the dense operator's side, the packed width's capacity
+# bucket and the benchmark artifact's l_max
+WV_N, WV_N_PAD, WV_WIDTH_CAP, WV_L_MAX = 7115, 7168, 640, 30
+
+
+@pytest.mark.parametrize("k", [1, 16, 64, 256])
+def test_dense_topk_compiles_for_v5e(one_chip, k):
+    """The dense push twin that the engine picks for wiki-Vote's shape:
+    its bfloat16 operator (103 MB) and the stacked MXU product fit, and
+    the product stays a bfloat16 x bfloat16 dot."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.topk import batched_topk_dense
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    ids = s((SOURCE_BATCH,), jnp.int32)
+    compiled = batched_topk_dense.lower(
+        s((WV_N, WV_WIDTH_CAP), jnp.int32),
+        s((WV_N, WV_WIDTH_CAP), jnp.float32), s((WV_N,), jnp.float32),
+        s((WV_N_PAD, WV_N_PAD), jnp.bfloat16), s((WV_N_PAD,), jnp.float32),
+        ids, s((), jnp.float32), n=WV_N, l_max=WV_L_MAX, k=k).compile()
+    _fits_hbm(compiled)
+    text = compiled.as_text()
+    assert "bf16[7168,7168]" in text
+    assert "convolution" in text or " dot(" in text
