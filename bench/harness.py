@@ -4,14 +4,22 @@ the run record that the metric readers in ``bench/metrics/`` reduce.
 Everything a cell needs is found by name: its entry in
 ``BENCHMARK.json``, its configuration file, ``bench/traffic/<mix>.json``,
 ``bench/limits/<cell>.json`` and ``bench/metrics/<metric>.py``.
+
+A mix with ``writes`` (``bench/traffic.py``) builds the index on the
+graph without its held-back edges, and a :class:`Writer` applies them
+during the window through the program's update contract
+(``update_index``, then ``ServeFrontend.swap_index``); the check holds
+each answer to the index and graph of the epoch that served it.
 """
 from __future__ import annotations
 
+import copy
 import gc
 import importlib.util
 import json
 import os
 import sys
+import threading
 import time
 from pathlib import Path
 
@@ -21,6 +29,7 @@ ROOT = Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
 WORK = ROOT / ".bench_work"
 DRAIN_S = 60.0            # an open-loop answer later than this is lost
+WRITE_TIMES = ("due", "update_start", "update_end", "swap_start", "swap_end")
 
 
 class NoChip(RuntimeError):
@@ -171,8 +180,94 @@ def warm_up(fe, mix: dict, n: int) -> None:
     t.result(timeout=600)
 
 
-def set_up(conf: dict, seed: int, path, rec: dict):
-    """Graph from the seed, index build into ``path``, mmap load; the
+class Writer:
+    """Applies a mix's write batches during the window, on a thread of
+    its own: at each batch's due time ``update_index`` on the served
+    index, then the frontend's barrier swap of the repaired index and
+    graph. ``records`` holds one dict per batch (times, the update's
+    report, the swap's metrics, or the error that stopped the writer);
+    ``epochs[e - 1]`` is a copy of the index as epoch e served it."""
+
+    def __init__(self, fe, idx, g, src, dst, batches, seed: int):
+        self.fe, self.idx, self.g = fe, idx, g
+        self.src, self.dst, self.batches = src, dst, batches
+        self.seed = seed
+        self.records = [{"batch": i, "edges": hi - lo, "applied": False}
+                        for i, (_, lo, hi) in enumerate(batches)]
+        self.epochs: list = []
+        self._stop = threading.Event()
+        self._thread = None
+
+    def start(self, t0: float) -> None:
+        self._thread = threading.Thread(target=self._run, args=(t0,),
+                                        name="bench-writer", daemon=True)
+        self._thread.start()
+
+    def join(self, deadline: float) -> list[dict]:
+        """Wait for the writer until ``deadline``, then stop it at its
+        next step; returns the batches' records as they stand. A batch
+        not applied by then counts as failed."""
+        self._thread.join(timeout=max(0.0, deadline - time.monotonic()))
+        self.stop()
+        out = [dict(w) for w in self.records]
+        if self._thread.is_alive():
+            for w in out:
+                if not w["applied"]:
+                    w.setdefault("error", "not done by the drain deadline")
+        return out
+
+    def stop(self) -> None:
+        self._stop.set()
+
+    def _run(self, t0: float) -> None:
+        import jax
+
+        from repro.core import build
+        from repro.graph import csr
+        none = np.zeros(0, np.int64)
+        for i, (due, lo, hi) in enumerate(self.batches):
+            w = self.records[i]
+            w["due"] = t0 + due
+            if self._stop.wait(max(0.0, t0 + due - time.monotonic())):
+                return
+            delta = csr.GraphDelta(add_src=self.src[lo:hi],
+                                   add_dst=self.dst[lo:hi],
+                                   del_src=none, del_dst=none)
+            try:
+                w["update_start"] = time.monotonic()
+                with jax.profiler.TraceAnnotation("bench.write.update"):
+                    rep = build.update_index(
+                        self.idx, self.g, delta,
+                        seed=(self.seed + 1 + i) % (2**31 - 1))
+                w["update_end"] = time.monotonic()
+                w["report"] = {
+                    "touched": len(rep.touched),
+                    "rows_repaired": rep.rows_repaired,
+                    "targets_seeded": rep.targets_seeded,
+                    "d_updated": rep.d_updated,
+                    "width_grew": bool(rep.width_grew),
+                    "stale": float(rep.stale),
+                    "eps_stale": float(rep.eps_stale),
+                    "needs_rebuild": bool(rep.needs_rebuild),
+                    "secs": rep.secs}
+                if self._stop.is_set():
+                    return
+                w["swap_start"] = time.monotonic()
+                with jax.profiler.TraceAnnotation("bench.write.swap"):
+                    w["swap"] = self.fe.swap_index(self.idx, rep.graph,
+                                                   rep.affected)
+                w["swap_end"] = time.monotonic()
+            except Exception as e:
+                w["error"] = f"{type(e).__name__}: {e}"
+                return
+            self.g = rep.graph
+            self.epochs.append(copy.deepcopy(self.idx))
+            w["applied"] = True
+
+
+def set_up(conf: dict, seed: int, path, rec: dict, hold_back: int = 0):
+    """Graph from the seed (without its last ``hold_back`` edges, which
+    the writer applies), index build into ``path``, mmap load; the
     seconds of each go into ``rec["setup"]``."""
     from bench import graphs
     from repro.core import build
@@ -180,7 +275,8 @@ def set_up(conf: dict, seed: int, path, rec: dict):
     from repro.graph import csr
     t = time.monotonic()
     src, dst = graphs.make_edges(conf["graph"])
-    g = csr.from_edges(conf["graph"]["n"], src, dst)
+    base = len(src) - hold_back
+    g = csr.from_edges(conf["graph"]["n"], src[:base], dst[:base])
     rec["setup"]["graph_s"] = time.monotonic() - t
     t = time.monotonic()
     bstats = build.build_index_scale(
@@ -199,10 +295,11 @@ def set_up(conf: dict, seed: int, path, rec: dict):
 
 
 def window(fe, rec: dict, spans, compiles, pauses, trace_dir,
-           t_start: float) -> None:
-    """Drive the cell's traffic for ``rec["seconds"]``, wait for what
-    the window sent (open loop), and record requests, batches, spans,
-    compiles and the device's peak memory into ``rec``."""
+           t_start: float, writer=None) -> None:
+    """Drive the cell's traffic for ``rec["seconds"]`` (and the
+    ``writer``'s batches), wait for what the window sent (open loop)
+    and for the writer, and record requests, batches, spans, compiles,
+    writes and the device's peak memory into ``rec``."""
     import jax
 
     from bench import traffic
@@ -216,6 +313,8 @@ def window(fe, rec: dict, spans, compiles, pauses, trace_dir,
     rec["setup_s"] = t0 - t_start
     drive = traffic.run_open if mix["loop"] == "open" else traffic.run_closed
     with jax.profiler.TraceAnnotation(trace_mod.WINDOW):
+        if writer is not None:
+            writer.start(t0)
         sent = drive(fe, mix, n, seconds, rng, t0)
         rest = t0 + seconds - time.monotonic()
         if rest > 0:
@@ -231,6 +330,8 @@ def window(fe, rec: dict, spans, compiles, pauses, trace_dir,
                 r.ticket.result(timeout=max(0.0, deadline - time.monotonic()))
             except Exception:
                 pass            # shed, failed or late: counted as lost
+    if writer is not None:
+        rec["writes"] = writer.join(t_end + DRAIN_S)
     compiles.on = False
     stats = jax.devices()[0].memory_stats() or {}
     rec["device"]["memory_peak_bytes"] = stats.get("peak_bytes_in_use")
@@ -257,7 +358,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, *,
     """One run; returns the run record. ``keep`` (the control) gets the
     record, the artifact, the edges and exact SimRank before they are
     dropped."""
-    from bench import check, reference
+    from bench import check, reference, traffic
     from bench import trace as trace_mod
     from repro.serve import FrontendConfig, ServeFrontend
 
@@ -269,6 +370,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, *,
         dev_peaks = None
     enable_compile_cache()
     conf, mix = cell["config"], cell["mix"]
+    batches = traffic.write_batches(mix, conf["graph"]["m"], seconds)
     rec = {"cell": cell["name"], "seed": seed, "seconds": seconds,
            "device": dev, "peaks": dev_peaks, "mix": mix, "setup": {}}
     WORK.mkdir(exist_ok=True)
@@ -276,43 +378,74 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, *,
     trace_dir = WORK / f"trace-{os.getpid()}"
     compiles = CompileCounter()
     pauses = GcPauses()
+    epoch_paths = []
+    writer = None
     try:
-        src, dst, g, idx, _ = set_up(conf, seed, path, rec)
+        hold_back = conf["graph"]["m"] - batches[0][1] if batches else 0
+        src, dst, g, idx, _ = set_up(conf, seed, path, rec, hold_back)
         t = time.monotonic()
         fe = ServeFrontend(idx, g, FrontendConfig())
         try:
             spans = Spans(fe.engines, trace)
+            if batches:
+                writer = Writer(fe, idx, g, src, dst, batches, seed)
             warm_up(fe, mix, idx.n)
             # the build's garbage goes now, so that every window starts
             # from the same heap
             gc.collect()
             rec["setup"]["warmup_s"] = time.monotonic() - t
             window(fe, rec, spans, compiles, pauses,
-                   trace_dir if trace else None, t_start)
+                   trace_dir if trace else None, t_start, writer)
         finally:
+            if writer is not None:
+                writer.stop()
             fe.close()
         rec["engine"] = fe.stats()["per_replica"][0]
         rec["shapes"] = [list(s) for s in rec["engine"]["unique_shapes"]]
         rec["trace"] = (trace_mod.reduce_xplane(trace_mod.find_xplane(str(trace_dir)))
                         if trace else None)
+        if writer is not None:
+            writer.fe = writer.idx = None
         del fe, idx, spans         # the engines' device arrays go
         gc.collect()
         t = time.monotonic()
+        base = len(src) - hold_back
         art = reference.read_artifact(str(path))
-        edges = reference.Edges.of(src, dst, art.n, art.c)
-        exact = reference.ExactSimRank(src, dst, art.n, art.c)
+        edges = reference.Edges.of(src[:base], dst[:base], art.n, art.c)
+        exact = reference.ExactSimRank(src[:base], dst[:base], art.n, art.c)
         rec["exact"] = {"seconds": time.monotonic() - t,
                         "steps": exact.steps, "bound": exact.bound}
+
+        def epoch_refs(e):
+            """Epoch e's index written to its own file and read back by
+            the reference, with the graph after its first e batches."""
+            epoch_paths.append(path.with_suffix(f".e{e}.sling"))
+            writer.epochs[e - 1].save(str(epoch_paths[-1]))
+            end = batches[e - 1][2]
+            a = reference.read_artifact(str(epoch_paths[-1]))
+            return (a, reference.Edges.of(src[:end], dst[:end], a.n, a.c),
+                    reference.ExactSimRank(src[:end], dst[:end], a.n, a.c))
+
+        refs = check.EpochRefs(epoch_refs, {0: (art, edges, exact)})
         rng_check = np.random.default_rng([int(seed), 3])
-        rec["checks"] = check.compare(mix, rec["requests"], art, edges,
-                                      exact, cell["limits"], rng_check)
+        rec["checks"] = check.compare(mix, rec["requests"], refs,
+                                      cell["limits"], rng_check,
+                                      rec.get("writes"))
         rec["check_s"] = time.monotonic() - t
+        if writer is not None:
+            swaps = check.swap_calls(rec["writes"])
+            rec["answers_at_swap"] = sum(
+                1 for r in rec["requests"] if r["done"] is not None
+                and len(set(check.epochs_of(r["done"], swaps))) == 2)
+            rec["epoch_check_s"] = dict(sorted(refs.seconds.items()))
         if keep is not None:
             keep(rec, art, edges, exact)
         return rec
     finally:
         pauses.close()
         path.unlink(missing_ok=True)
+        for p in epoch_paths:
+            p.unlink(missing_ok=True)
         if trace:
             import shutil
             shutil.rmtree(trace_dir, ignore_errors=True)
@@ -358,6 +491,13 @@ def print_result(line: dict, rec: dict) -> None:
                               "gen2_max_ms": 1e3 * max(gc2, default=0.0),
                               "gen2_sum_ms": 1e3 * sum(gc2)},
              "exact": rec.get("exact"), "check_s": rec.get("check_s")}
+    if "writes" in rec:
+        # times as seconds from the window's start
+        t0 = rec["window"]["t0"]
+        facts.update(writes=[{k: v - t0 if k in WRITE_TIMES else v
+                              for k, v in w.items()} for w in rec["writes"]],
+                     answers_at_swap=rec.get("answers_at_swap"),
+                     epoch_check_s=rec.get("epoch_check_s"))
     print("facts " + json.dumps(facts), flush=True)
     for name, c in line["checks"].items():
         print(f"check {name} {c['value']!r} limit {c['limit']!r}",

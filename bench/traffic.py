@@ -17,6 +17,17 @@ Mix keys:
 * ``k`` (top-k).
 * ``check_samples``: how many answers of the window are compared
   with the reference.
+* ``writes`` (optional): graph writes during the window,
+  ``{"hold_back": h, "batches": b, "first_at_s": f, "every_s": s}``.
+  The last ``h`` edges of the configuration's draw, in
+  ``graphs.make_edges`` order, are left out of the graph that is
+  built; they arrive as inserts in ``b`` equal consecutive batches,
+  batch i due ``f + i * s`` seconds after the window starts, so that
+  at the end the graph is the configuration's own and every seed does
+  the same writes. Refused: ``h`` not in [1, m) or not a multiple of
+  ``b``, more than ``MAX_WRITE_BATCHES`` batches (each epoch is
+  checked against its own exact SimRank), a last batch due at or
+  after the window's end. Deletions are not drawn.
 """
 from __future__ import annotations
 
@@ -25,6 +36,8 @@ import dataclasses
 import time
 
 import numpy as np
+
+MAX_WRITE_BATCHES = 4
 
 
 def zipf_weights(n: int, s: float) -> np.ndarray:
@@ -67,6 +80,33 @@ def open_schedule(rate: float, seconds: float, rng) -> np.ndarray:
     rng.shuffle(gaps)
     # the quantiles' mean is just under 1 / rate, so all fit
     return np.concatenate([[0.0], np.cumsum(gaps[:-1])])
+
+
+def write_batches(mix: dict, m: int,
+                  seconds: float) -> list[tuple[float, int, int]]:
+    """The mix's write batches as (due offset from the window's start,
+    first edge, end edge) slices of the configuration's ``m`` edges;
+    [] for a mix without writes."""
+    w = mix.get("writes")
+    if w is None:
+        return []
+    hold, count = int(w["hold_back"]), int(w["batches"])
+    first, every = float(w["first_at_s"]), float(w["every_s"])
+    if not 1 <= count <= MAX_WRITE_BATCHES:
+        raise ValueError(f"writes: {count} batches, not 1 to "
+                         f"{MAX_WRITE_BATCHES}")
+    if not 1 <= hold < m or hold % count:
+        raise ValueError(f"writes: hold_back {hold} must lie in [1, {m}) "
+                         f"and split into {count} equal batches")
+    if first < 0 or (count > 1 and every <= 0):
+        raise ValueError("writes: first_at_s must be >= 0 and every_s > 0")
+    last = first + (count - 1) * every
+    if last >= seconds:
+        raise ValueError(f"writes: the last batch is due at {last} s, "
+                         f"not inside the {seconds}-s window")
+    size, base = hold // count, m - hold
+    return [(first + i * every, base + i * size, base + (i + 1) * size)
+            for i in range(count)]
 
 
 @dataclasses.dataclass

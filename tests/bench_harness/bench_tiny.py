@@ -2,7 +2,9 @@
 tiny size. It is registered here, never in BENCHMARK.json."""
 from __future__ import annotations
 
+import contextlib
 import copy
+import functools
 import sys
 import time
 from pathlib import Path
@@ -41,13 +43,15 @@ def tiny_cell(mix: str) -> dict:
 
 
 def run_tiny(mix: str, seed: int = 2**31 + 11, seconds: float = 1.0,
-             trace: bool = False, keep=None):
-    """One harness run of the tiny cell on the CPU; returns
-    (cell, record, result line)."""
+             trace: bool = False, keep=None, writes=None):
+    """One harness run of the tiny cell on the CPU, with the mix's
+    ``writes`` where given; returns (cell, record, result line)."""
     import jax
 
     from bench import harness
     cell = tiny_cell(mix)
+    if writes is not None:
+        cell["mix"]["writes"] = writes
     # the harness turns on JAX's persistent cache; give the other
     # tests of this process their settings back
     names = ("jax_compilation_cache_dir",
@@ -64,3 +68,46 @@ def run_tiny(mix: str, seed: int = 2**31 + 11, seconds: float = 1.0,
             jax.config.update(k, v)
         compilation_cache.reset_cache()
     return cell, rec, harness.result_line(cell, rec, trace)
+
+
+@contextlib.contextmanager
+def fp32_artifact():
+    """While open, ``build_index_scale`` writes float32 values (no
+    quantization) and ``SlingIndex.load`` reads the file into memory
+    (no mmap): the only artifact the program's ``update_index``
+    repairs."""
+    from repro.core import build
+    from repro.core.index import SlingIndex
+    real_build, real_load = build.build_index_scale, SlingIndex.__dict__["load"]
+
+    def load(path, mmap=False, validate=None):
+        return real_load.__func__(path, mmap=False, validate=validate)
+
+    build.build_index_scale = functools.partial(real_build, quantize=None)
+    SlingIndex.load = staticmethod(load)
+    try:
+        yield
+    finally:
+        build.build_index_scale = real_build
+        SlingIndex.load = real_load
+
+
+def write_trial(workload: str, seed: int, seconds: float, writes: dict,
+                fp32: bool = False) -> None:
+    """One run on the chip of the BENCHMARK.json cell ``workload`` with
+    its mix's ``writes`` set, the cell so made registered nowhere;
+    ``fp32`` serves the artifact of :func:`fp32_artifact`. Prints as
+    ``bench/run.py`` does. From the checkout's root:
+
+        python3 -c "import sys; sys.path.insert(0, 'tests/bench_harness');
+            import bench_tiny; bench_tiny.write_trial('<cell>', <seed>, 30,
+            {'hold_back': 100, 'batches': 2, 'first_at_s': 2, 'every_s': 10},
+            fp32=True)"
+    """
+    from bench import harness
+    cell = harness.load_cell(workload)
+    cell["mix"]["writes"] = writes
+    with fp32_artifact() if fp32 else contextlib.nullcontext():
+        rec = harness.run(cell, seed, seconds, False,
+                          t_start=time.monotonic())
+    harness.print_result(harness.result_line(cell, rec, False), rec)
